@@ -25,17 +25,20 @@ import sys
 import time
 
 
-def main() -> None:
+def parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--devices", type=int, required=True,
-                    help="host device count this worker was launched with")
+                    help="devices of this point (host devices on the CPU)")
     ap.add_argument("--n-poly", type=int, default=2048)
     ap.add_argument("--n-limbs", type=int, default=2)
     ap.add_argument("--n-clients", type=int, default=8)
     ap.add_argument("--n-chunks", type=int, default=8)
     ap.add_argument("--reps", type=int, default=10)
-    args = ap.parse_args()
+    return ap
 
+
+def measure(args: argparse.Namespace) -> dict:
+    """One measurement point on jax.devices()[:args.devices]."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -48,7 +51,7 @@ def main() -> None:
 
     assert jax.device_count() >= args.devices, (
         f"worker expected {args.devices} devices, found "
-        f"{jax.device_count()}; the parent must set "
+        f"{jax.device_count()}; on the CPU the parent must set "
         "XLA_FLAGS=--xla_force_host_platform_device_count")
 
     ctx = ckks_params.make_context(n_poly=args.n_poly, n_limbs=args.n_limbs,
@@ -128,7 +131,11 @@ def main() -> None:
         "clients_ingested": ing.clients_ingested,
         "launches_per_update": launches_per_update,
     }
-    print(json.dumps(result))
+    return result
+
+
+def main() -> None:
+    print(json.dumps(measure(parser().parse_args())))
 
 
 if __name__ == "__main__":

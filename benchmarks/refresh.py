@@ -15,6 +15,7 @@ ART = os.path.join(os.path.dirname(__file__), "artifacts")
 
 
 def main():
+    pk = rf.peaks(rf.DRYRUN_TARGET)
     n = 0
     for fn in sorted(os.listdir(ART)):
         if not fn.endswith(".json"):
@@ -31,15 +32,15 @@ def main():
         r = art["roofline"]
         fused = min(fused, r["bytes_accessed"]) if r["bytes_accessed"] else fused
         r["fused_bytes"] = fused
-        r["memory_s"] = fused / rf.HBM_BW
-        r["memory_upper_s"] = r["bytes_accessed"] / rf.HBM_BW
+        r["memory_s"] = fused / pk.hbm_bytes_per_s
+        r["memory_upper_s"] = r["bytes_accessed"] / pk.hbm_bytes_per_s
         r["wire_bytes"] = colls.wire_bytes
-        r["collective_s"] = colls.wire_bytes / rf.ICI_BW
+        r["collective_s"] = colls.wire_bytes / pk.ici_bytes_per_s
         terms = {"compute": r["compute_s"], "memory": r["memory_s"],
                  "collective": r["collective_s"]}
         r["dominant"] = max(terms, key=terms.get)
         r["step_s"] = max(terms.values())
-        ideal = r["model_flops"] / rf.PEAK_FLOPS
+        ideal = r["model_flops"] / pk.bf16_flops
         r["roofline_fraction"] = ideal / r["step_s"] if r["step_s"] else 0.0
         art["collectives"] = {"counts": colls.counts,
                               "by_op_bytes": colls.by_op}

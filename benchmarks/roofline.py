@@ -1,14 +1,12 @@
 """Three-term roofline from compiled dry-run artifacts (DESIGN.md §6).
 
-Hardware model (TPU v5e-class target):
-    peak bf16 compute   197 TFLOP/s per chip
-    HBM bandwidth       819 GB/s per chip
-    ICI link bandwidth  ~50 GB/s per link
+Hardware model: the published peaks of the target chip, looked up by
+device_kind in repro.kernels.peaks (an unknown device is an error).
 
 Terms (seconds, per device — cost_analysis is per-device post-SPMD):
-    compute    = HLO flops / PEAK_FLOPS
-    memory     = HLO bytes accessed / HBM_BW
-    collective = sum over collective ops of wire-bytes / ICI_BW
+    compute    = HLO flops / peak bf16 flop/s
+    memory     = HLO bytes accessed / HBM bytes/s
+    collective = sum over collective ops of wire-bytes / ICI bytes/s
       ring formulas on per-device shapes from the partitioned module:
         all-gather      (g-1)/g * result_bytes
         reduce-scatter  (g-1)   * result_bytes   (= (g-1)/g * input)
@@ -21,9 +19,10 @@ from __future__ import annotations
 import dataclasses
 import re
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
+from repro.kernels.peaks import Peaks, peaks  # noqa: F401  (re-exported)
+
+# the chip (device_kind) the dry run's placeholder meshes stand for
+DRYRUN_TARGET = "TPU v5 lite"
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
@@ -170,6 +169,7 @@ class Roofline:
     wire_bytes: float
     model_flops: float          # analytic useful flops per device
     flops_ratio: float          # model_flops / hlo flops
+    peak_flops: float           # the target chip's bf16 peak
 
     @property
     def dominant(self) -> str:
@@ -186,7 +186,7 @@ class Roofline:
     def roofline_fraction(self) -> float:
         """Useful-compute fraction of the roofline-limited step: how close
         the step is to spending all its time on model flops at peak."""
-        ideal = self.model_flops / PEAK_FLOPS
+        ideal = self.model_flops / self.peak_flops
         return ideal / self.step_s if self.step_s > 0 else 0.0
 
     def to_dict(self) -> dict:
@@ -207,19 +207,22 @@ def model_flops_per_device(cfg, shape_kind: str, tokens: int,
 
 def build_roofline(cfg, shape_kind: str, tokens: int, n_devices: int,
                    flops: float, bytes_accessed: float,
-                   colls: CollectiveStats, fused_bytes: float) -> Roofline:
-    mf = model_flops_per_device(cfg, shape_kind, tokens, n_devices)
+                   colls: CollectiveStats, fused_bytes: float,
+                   pk: Peaks) -> Roofline:
+    mf = (model_flops_per_device(cfg, shape_kind, tokens, n_devices)
+          if shape_kind != "he_agg" else 0.0)
     if bytes_accessed:
         fused_bytes = min(fused_bytes, bytes_accessed)
     return Roofline(
-        compute_s=flops / PEAK_FLOPS,
-        memory_s=fused_bytes / HBM_BW,
-        collective_s=colls.wire_bytes / ICI_BW,
-        memory_upper_s=bytes_accessed / HBM_BW,
+        compute_s=flops / pk.bf16_flops,
+        memory_s=fused_bytes / pk.hbm_bytes_per_s,
+        collective_s=colls.wire_bytes / pk.ici_bytes_per_s,
+        memory_upper_s=bytes_accessed / pk.hbm_bytes_per_s,
         flops=flops,
         bytes_accessed=bytes_accessed,
         fused_bytes=fused_bytes,
         wire_bytes=colls.wire_bytes,
         model_flops=mf,
         flops_ratio=mf / flops if flops else 0.0,
+        peak_flops=pk.bf16_flops,
     )

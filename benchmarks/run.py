@@ -22,6 +22,8 @@ import subprocess
 import sys
 import time
 
+from repro.launch import compile_cache
+
 
 def _rows(title, rows, keys=None):
     print(f"\n== {title} ==")
@@ -383,31 +385,63 @@ def bench_wire():
 
 def _run_sharded_workers(module: str, bench: str, artifact: str,
                          ndevs=(1, 2, 8)) -> dict:
-    """Shared scaffold for the subprocess-per-device-count benchmarks.
+    """Shared scaffold for the per-device-count benchmarks.
 
-    jax locks the device count at first init, so each point runs `module`
-    in its own subprocess with
-    XLA_FLAGS=--xla_force_host_platform_device_count=<n> and collects the
-    worker's last stdout line as JSON.  Writes {bench, per_devices} to
-    `artifact` (repo root) only if EVERY point succeeded — a partial
-    artifact would silently shrink the README table.  Returns per_devices.
+    On the CPU, jax locks the device count at first init, so each point
+    runs `module` in its own subprocess with JAX_PLATFORMS=cpu and
+    XLA_FLAGS=--xla_force_host_platform_device_count=<n>, and collects the
+    worker's last stdout line as JSON.  That needs a parent that has not
+    imported JAX: the device count is fixed by then, and on a TPU host a
+    parent holding the chip would leave the children none.  So with JAX
+    imported this refuses to spawn — except on a TPU, where every point
+    that fits the chips runs in this process over jax.devices()[:n],
+    with no forced host devices.
+
+    Writes {bench, per_devices} to `artifact` (repo root) only if EVERY
+    point run succeeded — a partial artifact would silently shrink the
+    README table.  Returns per_devices.
     """
     root = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
     per_dev = {}
-    for ndev in ndevs:
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndev}"
-        env["PYTHONPATH"] = os.path.join(root, "src") + (
-            os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-        proc = subprocess.run(
-            [sys.executable, "-m", module, "--devices", str(ndev)],
-            cwd=root, env=env, capture_output=True, text=True)
-        out_lines = proc.stdout.strip().splitlines()
-        if proc.returncode != 0 or not out_lines:
+    if "jax" in sys.modules:
+        import importlib
+
+        import jax
+        if jax.default_backend() != "tpu":
             raise RuntimeError(
-                f"{bench} worker ndev={ndev} failed "
-                f"({artifact} left untouched):\n{proc.stdout}\n{proc.stderr}")
-        per_dev[str(ndev)] = json.loads(out_lines[-1])
+                f"{bench}: refusing to spawn device-count workers from a "
+                "process that has already imported JAX — its device count "
+                "is fixed and a child cannot share its devices.  Run "
+                f"`python -m benchmarks.run {bench.replace('_', '-')}` in "
+                "a fresh process (benchmarks.run schedules these modes "
+                "before any mode that imports JAX).")
+        worker = importlib.import_module(module)
+        n_chips = len(jax.devices())
+        for ndev in ndevs:
+            if ndev <= n_chips:
+                per_dev[str(ndev)] = worker.measure(
+                    worker.parser().parse_args(["--devices", str(ndev)]))
+        if not per_dev:
+            raise RuntimeError(f"{bench}: no point of {ndevs} fits "
+                               f"{n_chips} TPU chips")
+    else:
+        for ndev in ndevs:
+            env = dict(os.environ)
+            env["JAX_PLATFORMS"] = "cpu"
+            env["XLA_FLAGS"] = \
+                f"--xla_force_host_platform_device_count={ndev}"
+            env["PYTHONPATH"] = os.path.join(root, "src") + (
+                os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH")
+                else "")
+            proc = subprocess.run(
+                [sys.executable, "-m", module, "--devices", str(ndev)],
+                cwd=root, env=env, capture_output=True, text=True)
+            out_lines = proc.stdout.strip().splitlines()
+            if proc.returncode != 0 or not out_lines:
+                raise RuntimeError(
+                    f"{bench} worker ndev={ndev} failed ({artifact} left "
+                    f"untouched):\n{proc.stdout}\n{proc.stderr}")
+            per_dev[str(ndev)] = json.loads(out_lines[-1])
     from repro import obs
     with open(os.path.join(root, artifact), "w") as f:
         json.dump({"bench": bench, "provenance": obs.provenance(),
@@ -751,10 +785,13 @@ def main() -> None:
                     help="tune/selective/serve modes: tiny sweep, no repo "
                          "artifacts (CI exercises the full code path)")
     args = ap.parse_args()
+    compile_cache.enable()
     names = args.modes or list(ALL)
     unknown = [n for n in names if n not in ALL]
     if unknown:
         ap.error(f"unknown mode(s) {unknown}; choose from {list(ALL)}")
+    # the worker-spawning modes first: they need a parent without JAX
+    names.sort(key=lambda n: n not in ("agg-sharded", "uplink-sharded"))
     for n in names:
         t0 = time.time()
         if n in ("tune", "selective", "serve", "uplink-hybrid"):

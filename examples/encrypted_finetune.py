@@ -22,9 +22,11 @@ from repro.data import make_client_streams
 from repro.fl import ClientConfig, FLClient, FLRunConfig, FLTask
 from repro.models import build_model
 from repro.models.config import ModelConfig
+from repro.launch import compile_cache
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--rounds", type=int, default=8)
     ap.add_argument("--clients", type=int, default=4)

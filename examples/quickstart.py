@@ -15,9 +15,11 @@ from repro.core.secure_agg import AggregatorConfig, SelectiveHEAggregator
 from repro import wire
 from repro.wire import budget as wb
 from repro.wire import stream as ws
+from repro.launch import compile_cache
 
 
 def main():
+    compile_cache.enable()
     # 1. crypto context (paper defaults scaled down for a quick run:
     #    packing batch 512 slots, depth-1, two ~29-bit RNS limbs)
     ctx = ckks_params.make_context(n_poly=1024, n_limbs=2, delta_bits=24)

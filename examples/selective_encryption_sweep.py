@@ -25,9 +25,11 @@ from benchmarks.selective import fine_tune_and_sense, model_cfgs
 from repro.core import dp
 from repro.core.ckks import params as ckks_params
 from repro.core.secure_agg import AggregatorConfig, SelectiveHEAggregator
+from repro.launch import compile_cache
 
 
 def main():
+    compile_cache.enable()
     (_, cfg), = model_cfgs(smoke=True)
     print("fine-tuning 2 clients + computing per-parameter sensitivity "
           f"maps ({cfg.param_count()/1e3:.0f}k params)...")

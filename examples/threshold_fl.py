@@ -17,6 +17,7 @@ from repro.core.ckks import params as ckks_params
 from repro.core.secure_agg import AggregatorConfig
 from repro.data import make_client_streams
 from repro.fl import ClientConfig, FLClient, FLRunConfig, FLTask
+from repro.launch import compile_cache
 
 
 def microbenchmark(ctx):
@@ -51,6 +52,7 @@ def microbenchmark(ctx):
 
 
 def main():
+    compile_cache.enable()
     ctx = ckks_params.make_context(n_poly=2048, n_limbs=2, delta_bits=24)
     print("== threshold-HE microbenchmark (Appendix B / Figure 12) ==")
     microbenchmark(ctx)

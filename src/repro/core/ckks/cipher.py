@@ -65,6 +65,26 @@ class Ciphertext:
 # stream is invariant under sharding the chunk axis across devices — each
 # shard re-derives its local chunks' keys from the global chunk ids.  This
 # is the wire-v2 derivation contract (DESIGN.md §9).
+#
+# Every draw and key split here runs under `normative_streams()`: the
+# streams are wire format (DESIGN.md §9.2, §15.2) — a server regenerates a
+# client's `a` from its seed — so they must not follow JAX's default
+# threefry mode, which became `partitionable` in JAX 0.5 and reorders the
+# bits.  Model training and everything else keep the JAX default.
+
+
+def normative_streams():
+    """Context that pins the original (non-partitionable) threefry key
+    stream for the draws and splits inside it.  Used at trace time, so a
+    jitted graph traced inside keeps the pinned stream."""
+    return jax.threefry_partitionable(False)
+
+
+def split_key(key, num: int = 2):
+    """jax.random.split under the pinned stream (keys that feed the
+    normative draws)."""
+    with normative_streams():
+        return jax.random.split(key, num)
 
 
 def _ternary_residues(key, shape, qs):
@@ -72,7 +92,8 @@ def _ternary_residues(key, shape, qs):
 
     One draw of ternary symbols over `shape`, broadcast against the u32[L]
     prime table `qs` — the limb axis is never looped (and never drawn)."""
-    t = jax.random.randint(key, shape, 0, 3)[..., None, :]  # 0,1,2 ~ {-1,0,1}
+    with normative_streams():
+        t = jax.random.randint(key, shape, 0, 3)[..., None, :]  # {-1,0,1}
     qm1 = (jnp.asarray(qs) - np.uint32(1))[:, None]         # [L, 1]
     r = jnp.where(t == 0, qm1,
                   jnp.where(t == 1, np.uint32(0), np.uint32(1)))
@@ -82,8 +103,9 @@ def _ternary_residues(key, shape, qs):
 def _gaussian_residues(key, shape, qs, sigma: float):
     """Discrete-gaussian residues u32[..., L, N]: one normal draw over
     `shape`, centered-reduced against each limb prime."""
-    e = jnp.rint(float(sigma) * jax.random.normal(key, shape)) \
-        .astype(jnp.int32)
+    with normative_streams():
+        e = jnp.rint(float(sigma) * jax.random.normal(key, shape)) \
+            .astype(jnp.int32)
     return _ref.mod_reduce_centered(e[..., None, :],
                                     jnp.asarray(qs)[:, None])  # [..., L, N]
 
@@ -179,8 +201,9 @@ def _uniform_residues(key, shape, qs):
     """
     qs = jnp.asarray(qs, dtype=jnp.uint32)
     full = shape[:-1] + (qs.shape[0], shape[-1])
-    return jax.random.randint(key, full, jnp.uint32(0), qs[:, None],
-                              dtype=jnp.uint32)
+    with normative_streams():
+        return jax.random.randint(key, full, jnp.uint32(0), qs[:, None],
+                                  dtype=jnp.uint32)
 
 
 # ---------------------------------------------------------------------------
@@ -189,7 +212,7 @@ def _uniform_residues(key, shape, qs):
 
 @functools.partial(jax.jit, static_argnames=("ctx", "token"))
 def _keygen_graph(ctx: CkksContext, token, key):
-    k_s, k_a, k_e = jax.random.split(key, 3)
+    k_s, k_a, k_e = split_key(key, 3)
     n = ctx.n_poly
     qs = ctx.tables.qs
     s = ops.ntt_fwd(_ternary_residues(k_s, (n,), qs), ctx)        # [L, N]
@@ -230,7 +253,7 @@ def _encrypt_body(ctx: CkksContext, pk0_mont, pk1_mont, m_coeff, key,
     n = ctx.n_poly
     qs = ctx.tables.qs
     sigma = ctx.error_sigma
-    k3 = jax.vmap(lambda k: jax.random.split(k, 3))(
+    k3 = jax.vmap(lambda k: split_key(k, 3))(
         _chunk_keys(key, chunk_start, b))                    # [B, 3] keys
     m = ops.ntt_fwd(m_coeff, ctx)
     u = ops.ntt_fwd(jax.vmap(
@@ -303,6 +326,15 @@ def expand_a_rows(ctx: CkksContext, a_seed: int, start: int, count: int,
     matching keygen's treatment of `a`).
     """
     base = jax.random.PRNGKey(int(a_seed))
+    return _expand_a_graph(ctx, base, start, int(count), int(derive))
+
+
+@functools.partial(jax.jit, static_argnames=("ctx", "count", "derive"))
+def _expand_a_graph(ctx: CkksContext, base, start, count: int,
+                    derive: int):
+    """One dispatch per expansion — a streaming server calls this once per
+    arriving chunk.  `start` is traced, so every chunk of an update shares
+    one compiled graph."""
     keys = derive_chunk_keys(base, start, count, derive)
     return jax.vmap(
         lambda k: _uniform_residues(k, (ctx.n_poly,), ctx.tables.qs))(keys)

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import functools
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -143,8 +144,21 @@ def decode_jnp(residues, ctx: CkksContext, scale: float):
     the mod-Q centering run in (hi, lo) u32 pairs (mod-Q representatives are
     ~2**58 — f32 would quantize at 2**34, far above the CKKS noise floor).
     Only the small centered magnitude is converted to float.
+
+    The decode runs on the host's XLA CPU device whatever the default
+    backend: f32 division and the inverse FFT round differently on a TPU,
+    and a decrypted model must be the same bits on every machine (the gold
+    KATs pin them).  The result comes back as an uncommitted array, so
+    what is computed from it next runs on the default device.
     """
     assert residues.shape[1] == 2, "jnp decode path supports 2 limbs"
+    cpu = jax.devices("cpu")[0]
+    out = _decode_graph(jax.device_put(residues, cpu), ctx, float(scale))
+    return jnp.asarray(np.asarray(out))
+
+
+@functools.partial(jax.jit, static_argnames=("ctx", "scale"))
+def _decode_graph(residues, ctx: CkksContext, scale: float):
     n = ctx.n_poly
     q0, q1 = ctx.primes[0], ctx.primes[1]
     lc1 = ctx.limbs[1]

@@ -45,14 +45,14 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import obs
 from repro.core.ckks import encoding
 from repro.core.ckks.cipher import (DERIVE_FOLD_CHUNK, Ciphertext,
                                     _gaussian_residues, _ternary_residues,
-                                    _uniform_residues, derive_chunk_keys)
+                                    _uniform_residues, derive_chunk_keys,
+                                    split_key)
 from repro.core.ckks.params import CkksContext, LimbTables
 from repro.kernels import ops, ref as _ref
 
@@ -344,10 +344,10 @@ def _weighted_sum_graph(eng: ShardedHe, token, data, w_mont):
     def body(x, w, *tabs):
         return ops.apply("weighted_sum", local_tables(tabs), x, w)
 
-    f = shard_map(body, mesh=eng.mesh,
+    f = jax.shard_map(body, mesh=eng.mesh,
                   in_specs=(P(None, da, ma, None), P(None, ma))
                   + table_specs(ma),
-                  out_specs=P(da, ma, None), check_rep=False)
+                  out_specs=P(da, ma, None), check_vma=False)
     out = f(x, w_mont[:, :l], *table_arrays(t))[:r]
     return jnp.moveaxis(out.reshape(mid + (l, n)), -2, -3)
 
@@ -369,10 +369,10 @@ def _weighted_accum_graph(eng: ShardedHe, token, acc, ct, w_mont):
     def body(a, x, w, *tabs):
         return ops.apply("weighted_accum", local_tables(tabs), a, x, w)
 
-    f = shard_map(body, mesh=eng.mesh,
+    f = jax.shard_map(body, mesh=eng.mesh,
                   in_specs=(P(da, ma, None), P(da, ma, None), P(ma))
                   + table_specs(ma),
-                  out_specs=P(da, ma, None), check_rep=False)
+                  out_specs=P(da, ma, None), check_vma=False)
     out = f(a, x, w_mont[:l], *table_arrays(t))[:r]
     return jnp.moveaxis(out.reshape(mid + (l, n)), -2, -3)
 
@@ -395,10 +395,10 @@ def _weighted_accum_chunks_graph(eng: ShardedHe, token, accs, cts, w_mont):
         return ops.apply("weighted_accum_chunks", local_tables(tabs), a, x,
                          w)
 
-    f = shard_map(body, mesh=eng.mesh,
+    f = jax.shard_map(body, mesh=eng.mesh,
                   in_specs=(P(da, None, ma, None), P(da, None, ma, None),
                             P(da, ma)) + table_specs(ma),
-                  out_specs=P(da, None, ma, None), check_rep=False)
+                  out_specs=P(da, None, ma, None), check_vma=False)
     out = f(a, x, w, *table_arrays(t))[:r]
     return out.reshape((r,) + mid + (l, n))
 
@@ -414,7 +414,7 @@ def _keygen_graph(eng: ShardedHe, token, key):
     def body(key, *tabs):
         t = local_tables(tabs)
         q, qi = _col(t.qs), _col(t.qinv_negs)
-        k_s, k_a, k_e = jax.random.split(key, 3)
+        k_s, k_a, k_e = split_key(key, 3)
         s = ops.apply("ntt_fwd", t, _ternary_residues(k_s, (n,), t.qs))
         s_mont = _ref.mont_mul(s, jnp.broadcast_to(_col(t.r2s), s.shape),
                                q, qi)
@@ -432,9 +432,9 @@ def _keygen_graph(eng: ShardedHe, token, key):
             x, jnp.broadcast_to(_col(t.r2s), x.shape), q, qi)
         return s_mont, to_mont(pk0), to_mont(a)
 
-    f = shard_map(body, mesh=eng.mesh,
+    f = jax.shard_map(body, mesh=eng.mesh,
                   in_specs=(P(None),) + table_specs(ma),
-                  out_specs=(P(ma, None),) * 3, check_rep=False)
+                  out_specs=(P(ma, None),) * 3, check_vma=False)
     return f(key, *table_arrays(ctx.tables))
 
 
@@ -461,7 +461,7 @@ def _encrypt_body_sharded(eng: ShardedHe, pk0, pk1, m_coeff, key, tabs):
     sigma = float(ctx.error_sigma)
     t = local_tables(tabs)
     q = _col(t.qs)
-    k3 = jax.vmap(lambda k: jax.random.split(k, 3))(
+    k3 = jax.vmap(lambda k: split_key(k, 3))(
         _local_chunk_keys(eng, key, b_loc))
     m = ops.apply("ntt_fwd", t, m_coeff)
     u = ops.apply("ntt_fwd", t, jax.vmap(
@@ -475,17 +475,33 @@ def _encrypt_body_sharded(eng: ShardedHe, pk0, pk1, m_coeff, key, tabs):
     return jnp.stack([c0, c1], axis=-2)
 
 
-def _encrypt_shard_map(eng: ShardedHe):
+def _local_encode(eng: ShardedHe, values):
+    """Encode this data shard's rows f32[b_loc, slots] and keep the model
+    shard's limbs.  The encode FFT runs inside the shard_map body, on the
+    shard's own rows — outside it, XLA all-gathers the chunk axis to run
+    every chunk's FFT on every shard."""
+    l_loc = eng.ctx.n_limbs // eng.n_model
+    full = encoding.encode_jnp(values, eng.ctx)            # [b, L, N]
+    li = jax.lax.axis_index(eng.model_axis)
+    return jax.lax.dynamic_slice_in_dim(full, li * l_loc, l_loc, axis=1)
+
+
+def _encrypt_shard_map(eng: ShardedHe, encode: bool = False):
+    """Sharded pk encrypt; with `encode` the third operand is f32 values
+    (rows on `data`, replicated over `model`) encoded in the body."""
     da, ma = eng.data_axis, eng.model_axis
 
-    def body(pk0, pk1, m_coeff, key, *tabs):
-        return _encrypt_body_sharded(eng, pk0, pk1, m_coeff, key, tabs)
+    def body(pk0, pk1, m, key, *tabs):
+        if encode:
+            m = _local_encode(eng, m)
+        return _encrypt_body_sharded(eng, pk0, pk1, m, key, tabs)
 
-    return shard_map(
+    m_spec = P(da, None) if encode else P(da, ma, None)
+    return jax.shard_map(
         body, mesh=eng.mesh,
-        in_specs=(P(ma, None), P(ma, None), P(da, ma, None), P(None))
+        in_specs=(P(ma, None), P(ma, None), m_spec, P(None))
         + table_specs(ma),
-        out_specs=P(da, ma, None, None), check_rep=False)
+        out_specs=P(da, ma, None, None), check_vma=False)
 
 
 @functools.partial(jax.jit, static_argnames=("eng", "token"))
@@ -500,10 +516,10 @@ def _encrypt_coeffs_graph(eng: ShardedHe, token, pk0, pk1, m_coeff, key):
 
 @functools.partial(jax.jit, static_argnames=("eng", "token"))
 def _encrypt_values_graph(eng: ShardedHe, token, pk0, pk1, values, key):
-    m_coeff = encoding.encode_jnp(values, eng.ctx)
     t = eng.ctx.tables
-    x, r = _pad_rows(m_coeff, eng.n_data)
-    out = _encrypt_shard_map(eng)(pk0, pk1, x, key, *table_arrays(t))
+    x, r = _pad_rows(values, eng.n_data)
+    out = _encrypt_shard_map(eng, encode=True)(pk0, pk1, x, key,
+                                               *table_arrays(t))
     return out[:r]
 
 
@@ -540,18 +556,22 @@ def _encrypt_seeded_body_sharded(eng: ShardedHe, s_mont, m_coeff, key,
 
 
 def _encrypt_seeded_shard_map(eng: ShardedHe,
-                              derive: int = DERIVE_FOLD_CHUNK):
+                              derive: int = DERIVE_FOLD_CHUNK,
+                              encode: bool = False):
+    """Sharded seeded encrypt; `encode` as in _encrypt_shard_map."""
     da, ma = eng.data_axis, eng.model_axis
 
-    def body(s_mont, m_coeff, key, a_base, *tabs):
-        return _encrypt_seeded_body_sharded(eng, s_mont, m_coeff, key,
-                                            a_base, tabs, derive)
+    def body(s_mont, m, key, a_base, *tabs):
+        if encode:
+            m = _local_encode(eng, m)
+        return _encrypt_seeded_body_sharded(eng, s_mont, m, key, a_base,
+                                            tabs, derive)
 
-    return shard_map(
+    m_spec = P(da, None) if encode else P(da, ma, None)
+    return jax.shard_map(
         body, mesh=eng.mesh,
-        in_specs=(P(ma, None), P(da, ma, None), P(None), P(None))
-        + table_specs(ma),
-        out_specs=P(da, ma, None, None), check_rep=False)
+        in_specs=(P(ma, None), m_spec, P(None), P(None)) + table_specs(ma),
+        out_specs=P(da, ma, None, None), check_vma=False)
 
 
 @functools.partial(jax.jit, static_argnames=("eng", "token", "derive"))
@@ -568,11 +588,10 @@ def _encrypt_seeded_coeffs_graph(eng: ShardedHe, token, s_mont, m_coeff,
 @functools.partial(jax.jit, static_argnames=("eng", "token", "derive"))
 def _encrypt_seeded_values_graph(eng: ShardedHe, token, s_mont, values, key,
                                  a_base, derive: int = DERIVE_FOLD_CHUNK):
-    m_coeff = encoding.encode_jnp(values, eng.ctx)
     t = eng.ctx.tables
-    x, r = _pad_rows(m_coeff, eng.n_data)
-    out = _encrypt_seeded_shard_map(eng, derive)(s_mont, x, key, a_base,
-                                                 *table_arrays(t))
+    x, r = _pad_rows(values, eng.n_data)
+    out = _encrypt_seeded_shard_map(eng, derive, encode=True)(
+        s_mont, x, key, a_base, *table_arrays(t))
     return out[:r]
 
 
@@ -590,8 +609,8 @@ def _decrypt_graph(eng: ShardedHe, token, s_mont, data):
         phase = ops.apply("mul_add", t, c1, s[None], c0)
         return ops.apply("ntt_inv", t, phase)
 
-    f = shard_map(body, mesh=eng.mesh,
+    f = jax.shard_map(body, mesh=eng.mesh,
                   in_specs=(P(ma, None), P(da, ma, None, None))
                   + table_specs(ma),
-                  out_specs=P(da, ma, None), check_rep=False)
+                  out_specs=P(da, ma, None), check_vma=False)
     return f(s_mont, x, *table_arrays(t))[:b]

@@ -42,13 +42,13 @@ def threshold_keygen(ctx: CkksContext, key, n_parties: int
                      ) -> tuple[list[ThresholdParty], dict]:
     """Interactive additive keygen. Returns (parties, joint pk)."""
     n = ctx.n_poly
-    k_a, k_rest = jax.random.split(key)
+    k_a, k_rest = cipher.split_key(key)
     a = cipher._uniform_residues(k_a, (n,), ctx.tables.qs)      # common reference poly
     a_mont = ops.to_mont(a, ctx)
     parties = []
     b_sum = None
     for i in range(n_parties):
-        k_s, k_e = jax.random.split(jax.random.fold_in(k_rest, i))
+        k_s, k_e = cipher.split_key(jax.random.fold_in(k_rest, i))
         s_i = ops.ntt_fwd(cipher._ternary_residues(k_s, (n,), ctx.tables.qs), ctx)
         s_i_mont = ops.to_mont(s_i, ctx)
         e_i = ops.ntt_fwd(cipher._gaussian_residues(k_e, (n,), ctx.tables.qs, ctx.error_sigma), ctx)
@@ -92,7 +92,7 @@ def shamir_share_secret(ctx: CkksContext, sk: dict, key, n_parties: int,
                         threshold: int) -> list[ShamirParty]:
     """Split sk into Shamir shares over each limb field."""
     s = ops.from_mont(sk["s_mont"], ctx)     # [L, N] normal form
-    coeff_keys = jax.random.split(key, threshold - 1)
+    coeff_keys = cipher.split_key(key, threshold - 1)
     coeffs = [cipher._uniform_residues(k, (ctx.n_poly,), ctx.tables.qs)
               for k in coeff_keys]           # each [L, N]
     parties = []

@@ -111,9 +111,10 @@ def expand_pad_rows(n_poly: int, keystream_seed: int, start, count: int,
     base = _pad_base_key(keystream_seed)
     keys = cipher.derive_chunk_keys(base, start, count, derive)
     hi = jnp.uint32(1 << 31)      # u32 literal: 2**31 overflows int32 args
-    return jax.vmap(
-        lambda k: _PAD_LO + jax.random.randint(
-            k, (n_poly,), jnp.uint32(0), hi, dtype=jnp.uint32))(keys)
+    with cipher.normative_streams():
+        return jax.vmap(
+            lambda k: _PAD_LO + jax.random.randint(
+                k, (n_poly,), jnp.uint32(0), hi, dtype=jnp.uint32))(keys)
 
 
 def escrow_values(keystream_seed: int, ctx: CkksContext) -> np.ndarray:
@@ -182,8 +183,9 @@ def provision(ctx: CkksContext, sk: dict, key, a_seed: int, n_chunks: int,
     if keystream_seed is None:
         # four u16 digits from the secret noise key -> uniform 64-bit seed
         # (the same digit decomposition escrow_values() encrypts)
-        digits = jax.random.randint(jax.random.fold_in(key, _PAD_KEY_TAG),
-                                    (4,), 0, 1 << 16)
+        with cipher.normative_streams():
+            digits = jax.random.randint(
+                jax.random.fold_in(key, _PAD_KEY_TAG), (4,), 0, 1 << 16)
         keystream_seed = sum(int(d) << (16 * i)
                              for i, d in enumerate(np.asarray(digits)))
     keystream_seed = int(keystream_seed)
@@ -272,6 +274,18 @@ def _unmask_graph(ctx: CkksContext, token, d_rows, masked, a_base,
     return jnp.stack([c0, a], axis=-2)
 
 
+def check_unmask_rows(sm: ServerMaterials, chunk_idx: int,
+                      rows: int) -> None:
+    """ValueError unless chunk rows [chunk_idx, chunk_idx + rows) lie in
+    the range `sm` was provisioned for."""
+    r0 = int(chunk_idx) - sm.chunk_offset
+    if r0 < 0 or r0 + rows > sm.n_chunks:
+        raise ValueError(
+            f"chunk rows [{chunk_idx}, {chunk_idx + rows}) fall outside the "
+            f"provisioned range [{sm.chunk_offset}, "
+            f"{sm.chunk_offset + sm.n_chunks})")
+
+
 def server_unmask(ctx: CkksContext, sm: ServerMaterials, masked_rows,
                   chunk_idx: int) -> Ciphertext:
     """Masked u32[B, N] rows starting at global `chunk_idx` -> the full
@@ -283,12 +297,8 @@ def server_unmask(ctx: CkksContext, sm: ServerMaterials, masked_rows,
     straight into the existing StreamIngest accumulator."""
     masked = jnp.asarray(masked_rows, dtype=jnp.uint32)
     b = int(masked.shape[0])
+    check_unmask_rows(sm, chunk_idx, b)
     r0 = int(chunk_idx) - sm.chunk_offset
-    if r0 < 0 or r0 + b > sm.n_chunks:
-        raise ValueError(
-            f"chunk rows [{chunk_idx}, {chunk_idx + b}) fall outside the "
-            f"provisioned range [{sm.chunk_offset}, "
-            f"{sm.chunk_offset + sm.n_chunks})")
     data = _unmask_graph(ctx, ops.backend_token(), sm.d[r0:r0 + b], masked,
                          jax.random.PRNGKey(int(sm.a_seed)), chunk_idx,
                          int(sm.derive))

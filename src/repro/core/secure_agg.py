@@ -89,7 +89,7 @@ class SelectiveHEAggregator:
         single-device path (per-chunk key derivation, DESIGN.md §9).
         """
         enc_vals, plain = packing.split_by_mask(vec, self.part)
-        k_enc, k_dp = jax.random.split(key)
+        k_enc, k_dp = cipher.split_key(key)
         # encode FFT + encrypt run as ONE jitted dispatch (weights ->
         # ciphertext without leaving the graph)
         if sharded is not None:
@@ -116,7 +116,7 @@ class SelectiveHEAggregator:
         server's sharded aggregation."""
         vec, _ = packing.flatten_params(params)
         enc_vals, plain = packing.split_by_mask(vec, self.part)
-        k_enc, k_dp = jax.random.split(key)
+        k_enc, k_dp = cipher.split_key(key)
         if sharded is not None:
             ct = sharded.encrypt_values_seeded(sk, enc_vals, k_enc, a_seed,
                                                derive=derive)
@@ -142,7 +142,7 @@ class SelectiveHEAggregator:
         round stays bit-comparable end to end."""
         vec, _ = packing.flatten_params(params)
         enc_vals, plain = packing.split_by_mask(vec, self.part)
-        _, k_dp = jax.random.split(key)
+        _, k_dp = cipher.split_key(key)
         masked = transcipher.mask_values(self.ctx, cm,
                                          np.asarray(enc_vals))
         if self.cfg.dp_b > 0:

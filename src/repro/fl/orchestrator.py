@@ -209,6 +209,8 @@ class FLTask:
                     received.append(ReceivedUpdate(
                         cid=int(ci), update=upd,
                         n_samples=max(1, client.n_samples), round_sent=rnd))
+                # the next client trains beside this one's model otherwise
+                del local_params
         if not received and not wire_blobs:
             # total dropout: keep the old global model, log and move on
             return RoundLog(rnd, float("nan"), 0, dropped, 0,

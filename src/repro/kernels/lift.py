@@ -10,8 +10,9 @@ point — the lift is the only op whose OUTPUT traffic (L x the input)
 dominates, so the tile shape mirrors pointwise.py's and the limb index
 only picks the modulus.
 
-The grid is (L, ceil(B / block_b)); per-limb moduli come from the same
-u32[L] LimbTables plumbing as every other kernel.
+The grid is (L, ceil(B / block_b)) over the lane-dense u32[B, R, 128]
+input view (kernels/tiling.py); the per-limb moduli are prefetched into
+SMEM from the same u32[L] LimbTables row as every other kernel.
 """
 from __future__ import annotations
 
@@ -20,40 +21,43 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import tiling
 from repro.kernels import tune as _tune
 
 
-def _mod_lift_body(x_ref, q_ref, o_ref):
+def _mod_lift_body(q_ref, x_ref, o_ref):
     # full-range u32 % u32 — unlike the Montgomery ops there is no < 2**30
     # precondition here: masked words span [1, 2**32 - 2] by construction
     # (core/ckks/transcipher.py's pad window), and lax.rem on uint32 is
     # exact for the whole range.
-    o_ref[:, 0, :] = x_ref[...] % q_ref[0]
+    o_ref[...] = x_ref[...] % q_ref[pl.program_id(0)]
 
 
 @functools.lru_cache(maxsize=128)
-def _build(l: int, n: int, block_b: int, interpret: bool):
-    x_tile = pl.BlockSpec((block_b, n), lambda li, bi: (bi, 0))
-    o_tile = pl.BlockSpec((block_b, 1, n), lambda li, bi: (bi, li, 0))
-    scalar = pl.BlockSpec((1,), lambda li, bi: (li,))
+def _build(l: int, rows: int, block_b: int, interpret: bool):
+    x_tile = pl.BlockSpec((block_b, rows, tiling.LANES),
+                          lambda li, bi, *_: (bi, 0, 0))
+    o_tile = tiling.row_block(block_b, rows)
 
-    def call(x, qs):
+    @jax.jit
+    def call(qs, x):
         b = x.shape[0]
         return pl.pallas_call(
             _mod_lift_body,
-            grid=(l, pl.cdiv(b, block_b)),
-            in_specs=[x_tile, scalar],
-            out_specs=o_tile,
-            out_shape=jax.ShapeDtypeStruct((b, l, n), jnp.uint32),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(l, pl.cdiv(b, block_b)),
+                in_specs=[x_tile], out_specs=o_tile),
+            out_shape=jax.ShapeDtypeStruct((b, l, rows, tiling.LANES),
+                                           jnp.uint32),
             interpret=interpret,
-        )(x, qs)
+        )(qs, x)
 
     return call
 
 
-def mod_lift_fused(x, qs, *, block_b: int | None = None,
-                   interpret: bool = True):
+def mod_lift_fused(x, qs, *, interpret: bool, block_b: int | None = None):
     """out[..., l, :] = x[..., :] mod q_l, all limbs in one pallas_call.
 
     x: u32[..., N] full-range words; qs: u32[L].  block_b=None takes the
@@ -62,8 +66,9 @@ def mod_lift_fused(x, qs, *, block_b: int | None = None,
         block_b = _tune.default_block("mod_lift")
     n = x.shape[-1]
     batch = x.shape[:-1]
-    x2 = jnp.asarray(x, dtype=jnp.uint32).reshape((-1, n))
-    b = x2.shape[0]
+    x2 = tiling.rows_view(jnp.asarray(x, dtype=jnp.uint32).reshape((-1, n)))
+    b, rows = x2.shape[0], x2.shape[-2]
     l = qs.shape[0]
-    call = _build(l, n, min(block_b, b), interpret)
-    return call(x2, qs).reshape(batch + (l, n))
+    call = _build(l, rows, min(block_b, b), interpret)
+    out = call(jnp.asarray(qs, jnp.uint32), x2)
+    return tiling.unrows(out, n).reshape(batch + (l, n))

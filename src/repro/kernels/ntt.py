@@ -1,35 +1,52 @@
-"""Pallas TPU kernel: negacyclic NTT / iNTT, limb-fused over all RNS limbs.
+"""Pallas TPU kernels: negacyclic NTT / iNTT, limb-fused over all RNS limbs.
 
-Target: TPU VPU (u32 lanes). The grid is (L, ceil(B / block_b)): the RNS limb
-is a *grid coordinate*, not a Python loop, so one `pallas_call` covers the
-whole u32[B, L, N] tensor and kernel count no longer scales with limb depth.
-Each invocation holds a (block_b, N) tile of one limb plus that limb's
-N-entry twiddle row and scalar constants (q, -q^{-1}, N^{-1}R) in VMEM
-(block_b=8, N=8192 -> 288 KiB of VMEM, well under budget) and runs all
-log2(N) butterfly stages in-register.  The DIF/DIT pairing keeps both
-directions permutation-free (bit-reversed NTT domain).
+Target: TPU VPU (u32 lanes).  The grid is (L, ceil(B / block_b)): the RNS
+limb is a *grid coordinate* (a squeezed block dimension), so one
+`pallas_call` covers the whole u32[B, L, N] tensor.  Each polynomial is
+held lane-dense as u32[R, 128] (kernels/tiling.py), and every butterfly
+stage pairs flat index i with i XOR d:
 
-Constants arrive as stacked u32[L] / u32[L, N] tables (params.LimbTables);
-the BlockSpec index map selects the limb's row, so the kernel body is
-identical for every limb.  This is exactly what lets the sharded engine
+  * d < 128   — the partner sits in the same sublane row; a lane rotate
+                (`pltpu.roll` along lanes) brings it over;
+  * d >= 128  — the partner is d / 128 rows away; a sublane rotate brings
+                it over.
+
+No reshape ever splits the (8, 128) tile, which is what Mosaic requires.
+Each position selects its own half of the butterfly (CT forward: u + w v
+or u - w v; GS inverse: u + v or (u - v) w) with the twiddle of its group,
+so the arithmetic is the same mont_mul / mod_add / mod_sub on the same
+operands as kernels/ref.py — the outputs are bit-identical.  The rotate
+direction is read off a rotated iota instead of being assumed.
+
+A transform is a static *plan*: a sequence of butterfly stages ("ct" /
+"gs", distance d) and pointwise Montgomery multiplies ("mul").  Stages of
+one kind on one axis whose distances halve (or double) run as one
+fori_loop with a traced distance and rotation, so the kernel body holds a
+handful of butterflies, not log2(N).  The per-position twiddle of each
+step is gathered once per call from the LimbTables rows into a
+u32[L, T, R, 128] table (block: one limb's T tables, resident in VMEM
+across the batch axis of the grid):
+
+  * flat NTT ("pallas"): log2(N) stages over psi_rev_mont, plus the N^-1
+    multiply for the inverse;
+  * 4-step NTT ("pallas4", DESIGN.md §10): N = n1 * n2 with
+    j = j2 + n2 * j1 — log2(n1) stages at distances that are multiples of
+    n2 (the length-n1 sub-NTT over j1, twiddles psi1), one multiply by the
+    correction table, then log2(n2) stages at distances < n2 (the
+    length-n2 sub-NTT over j2, twiddles psi2).  In this layout the
+    4-step needs no transpose: its [br k1][br k2] output is already the
+    flat bit-reversed order.
+
+Constants come from the stacked u32[L] / u32[L, .] tables
+(params.LimbTables); q and -q^{-1} are prefetched into SMEM and read with
+the limb coordinate.  That is what lets the sharded engine
 (core/ckks/sharded.py, DESIGN.md §8) turn the limb grid axis into the
-`model` MESH axis: inside `shard_map` each shard passes its local table
-slice and launches this same kernel over its local limbs — the NTT runs
-within one limb's N coefficients, so limb sharding needs no collectives.
-
-Stages are unrolled in Python: every reshape has a static shape. On real TPU
-the flat kernel's final stages (t < 128 lanes) relayout across sublanes; the
-4-step transpose NTT below (`ntt4_fwd_fused` / `ntt4_inv_fused`, backend
-name "pallas4") is the fix: it decomposes the length-N transform into
-n1 x n2 sub-NTTs (64 x 128 for N=8192) so every butterfly stage pairs
-whole lane-contiguous rows, with one transpose between the two sub-NTT
-phases instead of log2(N) sublane shuffles.  DESIGN.md §10 documents the
-decomposition, the table layout (params.ntt4_* on LimbTables), and when
-each NTT implementation wins.
+`model` mesh axis with no collectives.
 
 Validated in interpret mode against repro/kernels/ref.py with exact integer
 equality (tests/test_kernels.py, tests/test_fused_engine.py,
-tests/test_ntt4.py, tests/test_gold.py).
+tests/test_ntt4.py, tests/test_gold.py) and compiled for the TPU at N=8192
+by tests/test_tpu_compile.py.
 """
 from __future__ import annotations
 
@@ -37,336 +54,271 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import ref as _ref
+from repro.kernels import tiling
 from repro.kernels import tune as _tune
 
-
-def _ntt_fwd_body(x_ref, psi_ref, q_ref, qinv_ref, o_ref, *, n: int):
-    x = x_ref[:, 0, :]
-    psi = psi_ref[0]
-    q = q_ref[0]
-    qinv_neg = qinv_ref[0]
-    m, t = 1, n
-    while m < n:
-        t //= 2
-        xs = x.reshape((-1, m, 2, t))
-        u = xs[:, :, 0, :]
-        s = psi[m:2 * m][None, :, None]
-        v = _ref.mont_mul(xs[:, :, 1, :], jnp.broadcast_to(s, u.shape), q,
-                          qinv_neg)
-        x = jnp.stack(
-            [_ref.mod_add(u, v, q), _ref.mod_sub(u, v, q)], axis=2
-        ).reshape((-1, n))
-        m *= 2
-    o_ref[:, 0, :] = x
-
-
-def _ntt_inv_body(x_ref, psi_inv_ref, q_ref, qinv_ref, ninv_ref, o_ref, *,
-                  n: int):
-    x = x_ref[:, 0, :]
-    psi_inv = psi_inv_ref[0]
-    q = q_ref[0]
-    qinv_neg = qinv_ref[0]
-    t, m = 1, n
-    while m > 1:
-        h = m // 2
-        xs = x.reshape((-1, h, 2, t))
-        u = xs[:, :, 0, :]
-        v = xs[:, :, 1, :]
-        s = psi_inv[h:2 * h][None, :, None]
-        lo = _ref.mod_add(u, v, q)
-        hi = _ref.mont_mul(_ref.mod_sub(u, v, q),
-                           jnp.broadcast_to(s, u.shape), q, qinv_neg)
-        x = jnp.stack([lo, hi], axis=2).reshape((-1, n))
-        t *= 2
-        m = h
-    x = _ref.mont_mul(x, jnp.broadcast_to(ninv_ref[0], x.shape), q, qinv_neg)
-    o_ref[:, 0, :] = x
+LANES = tiling.LANES
 
 
 # ---------------------------------------------------------------------------
-# 4-step transpose NTT (backend "pallas4", DESIGN.md §10)
+# plans: (kind, distance) steps + the static twiddle gather index
 # ---------------------------------------------------------------------------
-#
-# N = n1 * n2 (params.ntt4_split).  Writing j = j2 + n2*j1 and
-# k = k1 + n1*k2, the negacyclic NTT X[k] = sum_j x[j] psi^(j*(2k+1))
-# factors into
-#
-#   1. length-n1 negacyclic LN NTT over j1, root mu = psi^n2 — butterflies
-#      pair whole rows of the [n1, n2] matrix, the n2 columns ride along as
-#      the vectorized lane axis;
-#   2. elementwise correction by psi^(j2*(2*k1+1-n1)) (the pre-twist, the
-#      omega^(j2*k1) cross term, and the chi^(-j2) un-twist of step 4,
-#      folded into ONE precomputed Montgomery table);
-#   3. transpose [n1, n2] -> [n2, n1] — the single data relayout that
-#      replaces the flat kernel's per-stage sublane shuffles;
-#   4. length-n2 negacyclic LN NTT over j2, root chi = psi^n1.
-#
-# Both sub-NTTs keep the LN bit-reversed convention, and
-# bitrev(k1 + n1*k2, logN) = bitrev(k1)*n2 + bitrev(k2), so transposing the
-# [bitrev(k2)][bitrev(k1)] result back and flattening lands every output in
-# exactly the flat kernel's bit-reversed slot: all three backends are
-# bit-identical (tests/test_ntt4.py, tests/test_gold.py).
 
 
-def _ln_fwd_axis1(x, psi, q, qinv_neg, radix: int = 2):
-    """LN forward butterflies along axis 1 of x[b, len, spec]; psi: [len].
-
-    Identical recurrence to _ntt_fwd_body, but the transform axis is a
-    middle axis: the trailing spectator axis stays lane-contiguous through
-    every stage.
-
-    radix=4 fuses each PAIR of consecutive radix-2 stages into one pass
-    (a trailing radix-2 stage remains when log2(len) is odd), halving the
-    reshape/stack round trips for the short sub-transforms.  The fused
-    pass performs the exact same modular multiplies/adds on the exact
-    same elements as the two stages it replaces, so the output is
-    bit-identical — radix is launch geometry, not arithmetic
-    (DESIGN.md §12)."""
-    b, ln, spec = x.shape
-    m, t = 1, ln
-    while m < ln:
-        if radix == 4 and m * 4 <= ln:
-            t //= 4
-            xs = x.reshape((b, m, 2, 2, t, spec))
-            s1 = psi[m:2 * m][None, :, None, None, None]
-            u = xs[:, :, 0]                     # [b, m, 2(c), t, spec]
-            v = _ref.mont_mul(xs[:, :, 1], jnp.broadcast_to(s1, u.shape),
-                              q, qinv_neg)
-            y0 = _ref.mod_add(u, v, q)          # stage-1 outputs, p = 0/1
-            y1 = _ref.mod_sub(u, v, q)
-            s20 = psi[2 * m:4 * m:2][None, :, None, None]
-            s21 = psi[2 * m + 1:4 * m:2][None, :, None, None]
-            v0 = _ref.mont_mul(y0[:, :, 1],
-                               jnp.broadcast_to(s20, y0[:, :, 1].shape),
-                               q, qinv_neg)
-            v1 = _ref.mont_mul(y1[:, :, 1],
-                               jnp.broadcast_to(s21, y1[:, :, 1].shape),
-                               q, qinv_neg)
-            x = jnp.stack([_ref.mod_add(y0[:, :, 0], v0, q),
-                           _ref.mod_sub(y0[:, :, 0], v0, q),
-                           _ref.mod_add(y1[:, :, 0], v1, q),
-                           _ref.mod_sub(y1[:, :, 0], v1, q)],
-                          axis=2).reshape((b, ln, spec))
-            m *= 4
-            continue
-        t //= 2
-        xs = x.reshape((b, m, 2, t, spec))
-        u = xs[:, :, 0]
-        s = psi[m:2 * m][None, :, None, None]
-        v = _ref.mont_mul(xs[:, :, 1], jnp.broadcast_to(s, u.shape), q,
-                          qinv_neg)
-        x = jnp.stack([_ref.mod_add(u, v, q), _ref.mod_sub(u, v, q)],
-                      axis=2).reshape((b, ln, spec))
-        m *= 2
-    return x
-
-
-def _ln_inv_axis1(x, psi_inv, q, qinv_neg, radix: int = 2):
-    """GS inverse butterflies along axis 1 (no final 1/len scaling — the
-    caller applies one combined N^{-1} multiply after both phases).
-
-    radix=4 fuses stage pairs like _ln_fwd_axis1, same bit-identity
-    argument."""
-    b, ln, spec = x.shape
-    t, m = 1, ln
-    while m > 1:
-        if radix == 4 and m % 4 == 0:
-            h2 = m // 4
-            xs = x.reshape((b, h2, 2, 2, t, spec))   # [g2, a, dA, i]
-            u = xs[:, :, :, 0]                       # [b, h2, 2(a), t, spec]
-            v = xs[:, :, :, 1]
-            s1 = psi_inv[m // 2:m].reshape((h2, 2))[None, :, :, None, None]
-            lo = _ref.mod_add(u, v, q)               # stage-A outputs
-            hi = _ref.mont_mul(_ref.mod_sub(u, v, q),
-                               jnp.broadcast_to(s1, u.shape), q, qinv_neg)
-            s2 = psi_inv[h2:2 * h2][None, :, None, None]
-            d1_lo = _ref.mod_sub(lo[:, :, 0], lo[:, :, 1], q)
-            d1_hi = _ref.mod_sub(hi[:, :, 0], hi[:, :, 1], q)
-            x = jnp.stack(
-                [_ref.mod_add(lo[:, :, 0], lo[:, :, 1], q),
-                 _ref.mod_add(hi[:, :, 0], hi[:, :, 1], q),
-                 _ref.mont_mul(d1_lo, jnp.broadcast_to(s2, d1_lo.shape),
-                               q, qinv_neg),
-                 _ref.mont_mul(d1_hi, jnp.broadcast_to(s2, d1_hi.shape),
-                               q, qinv_neg)],
-                axis=2).reshape((b, ln, spec))
-            t *= 4
-            m = h2
-            continue
-        h = m // 2
-        xs = x.reshape((b, h, 2, t, spec))
-        u = xs[:, :, 0]
-        v = xs[:, :, 1]
-        s = psi_inv[h:2 * h][None, :, None, None]
-        lo = _ref.mod_add(u, v, q)
-        hi = _ref.mont_mul(_ref.mod_sub(u, v, q),
-                           jnp.broadcast_to(s, u.shape), q, qinv_neg)
-        x = jnp.stack([lo, hi], axis=2).reshape((b, ln, spec))
+def _flat_plan(n: int, inverse: bool):
+    """Flat LN NTT over a source row [psi (N) | n_inv (1, inverse only)]."""
+    steps, idx = [], []
+    i = np.arange(n)
+    if not inverse:
+        m = 1
+        while m < n:
+            t = n // (2 * m)
+            steps.append(("ct", t))
+            idx.append(m + i // (2 * t))
+            m *= 2
+        return steps, idx
+    t = 1
+    while t < n:
+        h = n // (2 * t)
+        steps.append(("gs", t))
+        idx.append(h + i // (2 * t))
         t *= 2
-        m = h
-    return x
+    steps.append(("mul", 0))
+    idx.append(np.full(n, n))
+    return steps, idx
 
 
-def _ntt4_fwd_body(x_ref, psi1_ref, psi2_ref, corr_ref, q_ref, qinv_ref,
-                   o_ref, *, n: int, n1: int, n2: int, radix: int = 2):
-    x = x_ref[:, 0, :]
-    b = x.shape[0]
-    q = q_ref[0]
-    qi = qinv_ref[0]
-    x = x.reshape((b, n1, n2))                       # [j1][j2]
-    x = _ln_fwd_axis1(x, psi1_ref[0], q, qi, radix)  # [br k1][j2]
-    corr = corr_ref[0].reshape((n1, n2))
-    x = _ref.mont_mul(x, jnp.broadcast_to(corr[None], x.shape), q, qi)
-    x = jnp.swapaxes(x, 1, 2)                        # [j2][br k1]
-    x = _ln_fwd_axis1(x, psi2_ref[0], q, qi, radix)  # [br k2][br k1]
-    o_ref[:, 0, :] = jnp.swapaxes(x, 1, 2).reshape((b, n))
+def _four_step_plan(n1: int, n2: int, inverse: bool):
+    """4-step NTT over a source row [psi1 (n1) | psi2 (n2) | corr (N) |
+    n_inv (1, inverse only)]."""
+    n = n1 * n2
+    i = np.arange(n)
+    j1, j2 = i // n2, i % n2
+    o2, oc = n1, n1 + n2
+
+    def fwd_phase(length, stride, pos, off):
+        out = []
+        m = 1
+        while m < length:
+            t = length // (2 * m)
+            out.append((("ct", t * stride), off + m + pos // (2 * t)))
+            m *= 2
+        return out
+
+    def inv_phase(length, stride, pos, off):
+        out = []
+        t = 1
+        while t < length:
+            h = length // (2 * t)
+            out.append((("gs", t * stride), off + h + pos // (2 * t)))
+            t *= 2
+        return out
+
+    corr = [(("mul", 0), oc + i)]
+    if not inverse:
+        pairs = fwd_phase(n1, n2, j1, 0) + corr + fwd_phase(n2, 1, j2, o2)
+    else:
+        pairs = (inv_phase(n2, 1, j2, o2) + corr + inv_phase(n1, n2, j1, 0)
+                 + [(("mul", 0), np.full(n, oc + n))])
+    return [p[0] for p in pairs], [p[1] for p in pairs]
 
 
-def _ntt4_inv_body(x_ref, psi1_inv_ref, psi2_inv_ref, corr_inv_ref, q_ref,
-                   qinv_ref, ninv_ref, o_ref, *, n: int, n1: int, n2: int,
-                   radix: int = 2):
-    x = x_ref[:, 0, :]
-    b = x.shape[0]
-    q = q_ref[0]
-    qi = qinv_ref[0]
-    x = x.reshape((b, n1, n2))                          # [br k1][br k2]
-    x = jnp.swapaxes(x, 1, 2)                           # [br k2][br k1]
-    x = _ln_inv_axis1(x, psi2_inv_ref[0], q, qi, radix)  # [j2][br k1]
-    x = jnp.swapaxes(x, 1, 2)                           # [br k1][j2]
-    corr_inv = corr_inv_ref[0].reshape((n1, n2))
-    x = _ref.mont_mul(x, jnp.broadcast_to(corr_inv[None], x.shape), q, qi)
-    x = _ln_inv_axis1(x, psi1_inv_ref[0], q, qi, radix)  # [j1][j2]
-    x = x.reshape((b, n))
-    x = _ref.mont_mul(x, jnp.broadcast_to(ninv_ref[0], x.shape), q, qi)
-    o_ref[:, 0, :] = x
+@functools.lru_cache(maxsize=64)
+def _plan(kind: str, n: int, n1: int, n2: int):
+    """(static steps, u32 gather index [T, R*128]) for one transform.  Pad
+    lanes (N < 128) gather slot 0; they never mix with real lanes."""
+    inverse = kind.endswith("inv")
+    if kind.startswith("ntt4"):
+        steps, idx = _four_step_plan(n1, n2, inverse)
+    else:
+        steps, idx = _flat_plan(n, inverse)
+    width = tiling.n_rows(n) * LANES
+    gather = np.zeros((len(idx), width), dtype=np.int32)
+    gather[:, :n] = np.stack(idx)
+    return tuple(steps), gather
+
+
+def _twiddles(src, gather, rows: int):
+    """src u32[L, S] -> per-position step tables u32[L, T, R, 128]."""
+    tw = jnp.take(jnp.asarray(src, dtype=jnp.uint32), jnp.asarray(gather),
+                  axis=1)
+    return tw.reshape((tw.shape[0], gather.shape[0], rows, LANES))
+
+
+# ---------------------------------------------------------------------------
+# kernel body
+# ---------------------------------------------------------------------------
+
+
+def _runs(steps) -> tuple:
+    """Group the plan into loops: consecutive butterfly stages of one kind
+    on one axis (lanes: distance < 128, sublanes: >= 128) whose distances
+    halve or double step by step run as one fori_loop with a traced
+    distance, so the kernel holds one butterfly per run instead of one
+    per stage.  -> ((kind, axis, first slot, count, d0, grows), ...)"""
+    runs = []
+    for slot, (kind, dist) in enumerate(steps):
+        axis = 0 if dist >= LANES else 1
+        if runs and kind != "mul":
+            k, ax, s0, cnt, d0, grows = runs[-1]
+            last = d0 << (cnt - 1) if grows else d0 >> (cnt - 1)
+            if k == kind and ax == axis and (
+                    (cnt == 1 and dist in (2 * d0, d0 // 2))
+                    or dist == (2 * last if grows else last // 2)):
+                runs[-1] = (k, ax, s0, cnt + 1, d0,
+                            dist > d0 if cnt == 1 else grows)
+                continue
+        runs.append((kind, axis, slot, 1, dist, False))
+    return tuple(runs)
+
+
+def _butterfly(x, w, idx, d, shift, axis: int, ct: bool, q, qi):
+    """One radix-2 stage pairing flat index i with i ^ d on a u32[R, 128]
+    polynomial (d: traced u32 distance, `shift` its rotation along
+    `axis`); w holds each position's group twiddle."""
+    size = x.shape[axis]
+    fwd = pltpu.roll(x, shift, axis)
+    bwd = pltpu.roll(x, size - shift, axis)
+    partner = jnp.where(pltpu.roll(idx, shift, axis) == (idx ^ d), fwd, bwd)
+    lo = (idx & d) == np.uint32(0)
+    u = jnp.where(lo, x, partner)
+    v = jnp.where(lo, partner, x)
+    if ct:
+        wv = _ref.mont_mul(v, w, q, qi)
+        return jnp.where(lo, _ref.mod_add(u, wv, q), _ref.mod_sub(u, wv, q))
+    s = jnp.where(lo, _ref.mod_add(u, v, q), _ref.mod_sub(u, v, q))
+    return jnp.where(lo, s, _ref.mont_mul(s, w, q, qi))
+
+
+def _ntt_body(q_ref, qi_ref, x_ref, tw_ref, o_ref, *, runs, rows: int,
+              block_b: int):
+    li = pl.program_id(0)
+    q = q_ref[li]
+    qi = qi_ref[li]
+    shape = (rows, LANES)
+    idx = (jax.lax.broadcasted_iota(jnp.uint32, shape, 0) * np.uint32(LANES)
+           + jax.lax.broadcasted_iota(jnp.uint32, shape, 1))
+
+    def run_loop(x, kind, axis, s0, count, d0, grows):
+        def stage(k, x):
+            ku = k.astype(jnp.uint32)
+            d = (np.uint32(d0) << ku) if grows else (np.uint32(d0) >> ku)
+            shift = (d >> np.uint32(7) if axis == 0 else d).astype(jnp.int32)
+            return _butterfly(x, tw_ref[s0 + k], idx, d, shift, axis,
+                              kind == "ct", q, qi)
+        return jax.lax.fori_loop(0, count, stage, x)
+
+    def one(r, carry):
+        x = x_ref[r]
+        for kind, axis, s0, count, d0, grows in runs:
+            if kind == "mul":
+                x = _ref.mont_mul(x, tw_ref[s0], q, qi)
+            else:
+                x = run_loop(x, kind, axis, s0, count, d0, grows)
+        o_ref[r] = x
+        return carry
+
+    jax.lax.fori_loop(0, block_b, one, 0)
 
 
 @functools.lru_cache(maxsize=128)
-def _build(direction: str, l: int, n: int, block_b: int, interpret: bool):
-    tile = pl.BlockSpec((block_b, 1, n), lambda li, bi: (bi, li, 0))
-    row = pl.BlockSpec((1, n), lambda li, bi: (li, 0))
-    scalar = pl.BlockSpec((1,), lambda li, bi: (li,))
-    if direction == "fwd":
-        body = functools.partial(_ntt_fwd_body, n=n)
-        in_specs = [tile, row, scalar, scalar]
-    else:
-        body = functools.partial(_ntt_inv_body, n=n)
-        in_specs = [tile, row, scalar, scalar, scalar]
+def _build(steps: tuple, n_tables: int, l: int, rows: int, block_b: int,
+           interpret: bool):
+    body = functools.partial(_ntt_body, runs=_runs(steps), rows=rows,
+                             block_b=block_b)
+    tile = tiling.row_block(block_b, rows)
+    tables = pl.BlockSpec((None, n_tables, rows, LANES),
+                          lambda li, bi, *_: (li, 0, 0, 0))
 
-    def call(x, *tables):
+    @jax.jit
+    def call(qs, qinv_negs, x, tw):
         b = x.shape[0]
         return pl.pallas_call(
             body,
-            grid=(l, pl.cdiv(b, block_b)),
-            in_specs=in_specs,
-            out_specs=tile,
-            out_shape=jax.ShapeDtypeStruct((b, l, n), jnp.uint32),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2, grid=(l, pl.cdiv(b, block_b)),
+                in_specs=[tile, tables], out_specs=tile),
+            out_shape=jax.ShapeDtypeStruct(x.shape, jnp.uint32),
             interpret=interpret,
-        )(x, *tables)
+        )(qs, qinv_negs, x, tw)
 
     return call
 
 
-def _flatten(x):
+def _run(kind: str, x, src, qs, qinv_negs, block_b, interpret: bool,
+         n1: int = 0, n2: int = 0):
+    if block_b is None:
+        block_b = _tune.default_block(
+            "ntt_inv" if kind.endswith("inv") else "ntt_fwd")
     l, n = x.shape[-2], x.shape[-1]
-    return x.reshape((-1, l, n)), x.shape[:-2]
+    batch = x.shape[:-2]
+    x2 = tiling.rows_view(jnp.asarray(x, jnp.uint32).reshape((-1, l, n)))
+    b, rows = x2.shape[0], x2.shape[-2]
+    steps, gather = _plan(kind, n, n1, n2)
+    call = _build(steps, len(steps), l, rows, min(block_b, b), interpret)
+    out = call(jnp.asarray(qs, jnp.uint32), jnp.asarray(qinv_negs, jnp.uint32),
+               x2, _twiddles(src, gather, rows))
+    return tiling.unrows(out, n).reshape(batch + (l, n))
 
 
-def ntt_fwd_fused(x, psi_rev_mont, qs, qinv_negs, *, block_b: int | None = None,
-                  interpret: bool = True):
+def _col(v):
+    return jnp.asarray(v, jnp.uint32)[:, None]
+
+
+def ntt_fwd_fused(x, psi_rev_mont, qs, qinv_negs, *, interpret: bool,
+                  block_b: int | None = None):
     """x: u32[..., L, N] natural -> bit-reversed NTT domain, all limbs in one
     pallas_call.  psi_rev_mont: u32[L, N]; qs, qinv_negs: u32[L].
 
     block_b=None takes the shared default from tune.DEFAULT_BLOCK — the
     registry (kernels/ops.py) threads tuned values here instead."""
-    if block_b is None:
-        block_b = _tune.default_block("ntt_fwd")
-    x2, batch = _flatten(x)
-    b, l, n = x2.shape
-    call = _build("fwd", l, n, min(block_b, b), interpret)
-    return call(x2, psi_rev_mont, qs, qinv_negs).reshape(batch + (l, n))
+    return _run("ntt_fwd", x, psi_rev_mont, qs, qinv_negs, block_b,
+                interpret)
 
 
 def ntt_inv_fused(x, psi_inv_rev_mont, n_inv_monts, qs, qinv_negs, *,
-                  block_b: int | None = None, interpret: bool = True):
+                  interpret: bool, block_b: int | None = None):
     """x: u32[..., L, N] bit-reversed NTT domain -> natural order."""
-    if block_b is None:
-        block_b = _tune.default_block("ntt_inv")
-    x2, batch = _flatten(x)
-    b, l, n = x2.shape
-    call = _build("inv", l, n, min(block_b, b), interpret)
-    return call(x2, psi_inv_rev_mont, qs, qinv_negs,
-                n_inv_monts).reshape(batch + (l, n))
-
-
-@functools.lru_cache(maxsize=128)
-def _build4(direction: str, l: int, n: int, n1: int, n2: int, block_b: int,
-            radix: int, interpret: bool):
-    tile = pl.BlockSpec((block_b, 1, n), lambda li, bi: (bi, li, 0))
-    row1 = pl.BlockSpec((1, n1), lambda li, bi: (li, 0))
-    row2 = pl.BlockSpec((1, n2), lambda li, bi: (li, 0))
-    rown = pl.BlockSpec((1, n), lambda li, bi: (li, 0))
-    scalar = pl.BlockSpec((1,), lambda li, bi: (li,))
-    if direction == "fwd":
-        body = functools.partial(_ntt4_fwd_body, n=n, n1=n1, n2=n2,
-                                 radix=radix)
-        in_specs = [tile, row1, row2, rown, scalar, scalar]
-    else:
-        body = functools.partial(_ntt4_inv_body, n=n, n1=n1, n2=n2,
-                                 radix=radix)
-        in_specs = [tile, row1, row2, rown, scalar, scalar, scalar]
-
-    def call(x, *tables):
-        b = x.shape[0]
-        return pl.pallas_call(
-            body,
-            grid=(l, pl.cdiv(b, block_b)),
-            in_specs=in_specs,
-            out_specs=tile,
-            out_shape=jax.ShapeDtypeStruct((b, l, n), jnp.uint32),
-            interpret=interpret,
-        )(x, *tables)
-
-    return call
+    src = jnp.concatenate([jnp.asarray(psi_inv_rev_mont, jnp.uint32),
+                           _col(n_inv_monts)], axis=1)
+    return _run("ntt_inv", x, src, qs, qinv_negs, block_b, interpret)
 
 
 def ntt4_fwd_fused(x, psi1_mont, psi2_mont, corr_mont, qs, qinv_negs, *,
-                   block_b: int | None = None, radix: int = 2,
-                   interpret: bool = True):
+                   interpret: bool, block_b: int | None = None):
     """4-step forward negacyclic NTT, bit-identical to ntt_fwd_fused.
 
     x: u32[..., L, N] natural -> bit-reversed NTT domain.  Tables come from
     params.LimbTables: psi1_mont u32[L, n1], psi2_mont u32[L, n2],
     corr_mont u32[L, N] (N = n1*n2; the split is read off the table shapes,
-    so retabled variants from params.retable_ntt4 change it here).  radix
-    picks the sub-NTT butterfly grouping (2 or 4) — launch geometry only,
-    never bits."""
-    if block_b is None:
-        block_b = _tune.default_block("ntt_fwd")
-    x2, batch = _flatten(x)
-    b, l, n = x2.shape
+    so retabled variants from params.retable_ntt4 change it here)."""
     n1, n2 = psi1_mont.shape[-1], psi2_mont.shape[-1]
-    assert n1 * n2 == n, (n1, n2, n)
-    call = _build4("fwd", l, n, n1, n2, min(block_b, b), radix, interpret)
-    return call(x2, psi1_mont, psi2_mont, corr_mont, qs,
-                qinv_negs).reshape(batch + (l, n))
+    if n1 * n2 != x.shape[-1]:
+        raise ValueError(f"4-step split {n1} x {n2} != N={x.shape[-1]}")
+    src = jnp.concatenate([jnp.asarray(t, jnp.uint32)
+                           for t in (psi1_mont, psi2_mont, corr_mont)],
+                          axis=1)
+    return _run("ntt4_fwd", x, src, qs, qinv_negs, block_b, interpret,
+                n1, n2)
 
 
 def ntt4_inv_fused(x, psi1_inv_mont, psi2_inv_mont, corr_inv_mont,
-                   n_inv_monts, qs, qinv_negs, *, block_b: int | None = None,
-                   radix: int = 2, interpret: bool = True):
+                   n_inv_monts, qs, qinv_negs, *, interpret: bool,
+                   block_b: int | None = None):
     """4-step inverse negacyclic NTT, bit-identical to ntt_inv_fused.
 
     x: u32[..., L, N] bit-reversed NTT domain -> natural order."""
-    if block_b is None:
-        block_b = _tune.default_block("ntt_inv")
-    x2, batch = _flatten(x)
-    b, l, n = x2.shape
     n1, n2 = psi1_inv_mont.shape[-1], psi2_inv_mont.shape[-1]
-    assert n1 * n2 == n, (n1, n2, n)
-    call = _build4("inv", l, n, n1, n2, min(block_b, b), radix, interpret)
-    return call(x2, psi1_inv_mont, psi2_inv_mont, corr_inv_mont, qs,
-                qinv_negs, n_inv_monts).reshape(batch + (l, n))
+    if n1 * n2 != x.shape[-1]:
+        raise ValueError(f"4-step split {n1} x {n2} != N={x.shape[-1]}")
+    src = jnp.concatenate([jnp.asarray(t, jnp.uint32)
+                           for t in (psi1_inv_mont, psi2_inv_mont,
+                                     corr_inv_mont)] + [_col(n_inv_monts)],
+                          axis=1)
+    return _run("ntt4_inv", x, src, qs, qinv_negs, block_b, interpret,
+                n1, n2)

@@ -20,14 +20,13 @@ Each op is an entry in an op-table mapping backend name -> implementation:
                (kernel body executed in Python) for validation; on TPU they
                compile natively.
   * "pallas4" — like "pallas", but ntt_fwd/ntt_inv dispatch to the 4-step
-               transpose NTT kernels (kernels/ntt.py, DESIGN.md §10): the
-               lane-efficient layout for real-TPU butterflies below 128
-               lanes.  Every non-NTT op shares the "pallas" kernels.  All
-               concrete backends are bit-identical (tests/test_gold.py).
+               NTT kernels (kernels/ntt.py, DESIGN.md §10).  Every non-NTT
+               op shares the "pallas" kernels.  All concrete backends are
+               bit-identical (tests/test_gold.py).
   * "auto"   — per-op, per-SHAPE resolution through the kernels/tune.py
                tuning cache (DESIGN.md §12): a cache hit runs the measured
                winner (concrete backend + launch config — block_b, ntt4
-               split, butterfly radix), a miss runs the platform fallback
+               split), a miss runs the platform fallback
                with the shared defaults.  Resolution happens at trace
                time (shapes are static under jit) and the tuner's cache
                generation is folded into `backend_token()`, so cached
@@ -169,18 +168,14 @@ def _ntt_inv_pallas(t, x, cfg=None):
 def _ntt_fwd_pallas4(t, x, cfg=None):
     return _ntt.ntt4_fwd_fused(x, t.ntt4_psi1_mont, t.ntt4_psi2_mont,
                                t.ntt4_corr_mont, t.qs, t.qinv_negs,
-                               block_b=_blk(cfg),
-                               radix=cfg.radix if cfg is not None else 2,
-                               interpret=_interpret())
+                               block_b=_blk(cfg), interpret=_interpret())
 
 
 def _ntt_inv_pallas4(t, x, cfg=None):
     return _ntt.ntt4_inv_fused(x, t.ntt4_psi1_inv_mont,
                                t.ntt4_psi2_inv_mont, t.ntt4_corr_inv_mont,
                                t.n_inv_monts, t.qs, t.qinv_negs,
-                               block_b=_blk(cfg),
-                               radix=cfg.radix if cfg is not None else 2,
-                               interpret=_interpret())
+                               block_b=_blk(cfg), interpret=_interpret())
 
 
 def _mul_add_ref(t, x, y_mont, z, cfg=None):

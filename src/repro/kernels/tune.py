@@ -7,16 +7,16 @@ for `params.ntt4_split`, and a process-wide env var for the flat-vs-4-step
 NTT choice.  This module makes all of them a *measured, per-shape*
 decision:
 
-  * **config** — `KernelConfig(block_b, ntt4_split, radix)` is the full
+  * **config** — `KernelConfig(block_b, ntt4_split)` is the full
     launch geometry of one kernel invocation.  `DEFAULT_BLOCK` is the one
     table every kernel default routes through (kernels/{ntt,pointwise,
     he_agg}.py take `block_b=None` and ask here), so block sizes live in
     exactly one place.
   * **sweep** — `sweep_op()` measures every candidate
-    (backend x block_b x ntt4_split x radix) for one `(op, N, L, B)`
+    (backend x block_b x ntt4_split) for one `(op, N, L, B)`
     point with `block_until_ready` wall time, pruning candidates whose
     roofline-model estimate (memory traffic / HBM bandwidth + per-grid-
-    step launch overhead, constants from benchmarks/roofline.py) is
+    step launch overhead, peaks from kernels/peaks.py) is
     hopeless before ever running them.  The default config is always a
     candidate, so the winner is never slower than the default at
     measurement time.
@@ -34,7 +34,7 @@ decision:
     config may have changed.
 
 Correctness invariant: a config only changes LAUNCH GEOMETRY — block
-sizes, sub-NTT factorization, butterfly radix — never the modular
+sizes, sub-NTT factorization — never the modular
 arithmetic, so every candidate reproduces the gold KATs bit-exactly
 (tests/test_tune.py sweeps the full grid against tests/golden/).
 
@@ -69,7 +69,6 @@ DEFAULT_BLOCK = {
 }
 
 BLOCK_CANDIDATES = (1, 2, 4, 8, 16)
-RADIX_CANDIDATES = (2, 4)
 NTT_OPS = ("ntt_fwd", "ntt_inv")
 
 CACHE_VERSION = 1
@@ -84,16 +83,6 @@ PRUNE_RATIO = 3.0
 LAUNCH_OVERHEAD_S = 2e-6
 
 
-def _roofline_constants() -> tuple[float, float]:
-    """(HBM bytes/s, peak flop/s) from benchmarks/roofline.py when the
-    repo root is importable, else that file's TPU v5e-class constants."""
-    try:
-        from benchmarks.roofline import HBM_BW, PEAK_FLOPS
-        return HBM_BW, PEAK_FLOPS
-    except ImportError:
-        return 819e9, 197e12
-
-
 @dataclasses.dataclass(frozen=True)
 class KernelConfig:
     """Launch geometry of one kernel invocation — never arithmetic.
@@ -101,33 +90,27 @@ class KernelConfig:
     block_b: batch rows per grid step (block_k for the chunk kernel).
     ntt4_split: (n1, n2) sub-NTT factorization, None = params.ntt4_split's
         sqrt heuristic (4-step NTT ops only).
-    radix: butterfly radix inside the 4-step sub-NTTs (2 or 4; radix 4
-        fuses two butterfly stages per pass, halving stage count for the
-        length-64/128 sub-transforms).
     """
 
     block_b: int
     ntt4_split: tuple[int, int] | None = None
-    radix: int = 2
 
     def to_json(self) -> dict:
         return {"block_b": self.block_b,
                 "ntt4_split": list(self.ntt4_split)
-                if self.ntt4_split else None,
-                "radix": self.radix}
+                if self.ntt4_split else None}
 
     @classmethod
     def from_json(cls, doc: dict) -> "KernelConfig":
         split = doc.get("ntt4_split")
         return cls(block_b=int(doc["block_b"]),
                    ntt4_split=tuple(int(x) for x in split) if split
-                   else None,
-                   radix=int(doc.get("radix", 2)))
+                   else None)
 
 
 def default_config(op: str) -> KernelConfig:
     """The config a dispatch uses with no cache entry: the DEFAULT_BLOCK
-    tile, sqrt split, radix-2 — exactly the pre-autotuner behaviour."""
+    tile and the sqrt split — exactly the pre-autotuner behaviour."""
     return KernelConfig(block_b=DEFAULT_BLOCK[op])
 
 
@@ -330,7 +313,7 @@ class Candidate:
 
     @property
     def is_default(self) -> bool:
-        return self.config.ntt4_split is None and self.config.radix == 2
+        return self.config.ntt4_split is None
 
 
 def candidates(op: str, n: int, l: int, b: int,
@@ -341,9 +324,9 @@ def candidates(op: str, n: int, l: int, b: int,
         meaningless there) and the `pallas` kernel at each
         BLOCK_CANDIDATES tile <= B;
       * NTT ops additionally: `pallas4` at every
-        `params.ntt4_split_candidates(N)` x RADIX_CANDIDATES x block.
+        `params.ntt4_split_candidates(N)` x block.
 
-    The default config (DEFAULT_BLOCK, sqrt split, radix 2) on the
+    The default config (DEFAULT_BLOCK, sqrt split) on the
     platform fallback backend is always present, so a sweep can only ever
     match or beat it.
     """
@@ -357,10 +340,9 @@ def candidates(op: str, n: int, l: int, b: int,
         out.append(Candidate("pallas", KernelConfig(block_b=blk)))
     if op in NTT_OPS:
         for n1, n2 in ckks_params.ntt4_split_candidates(n):
-            for radix in RADIX_CANDIDATES:
-                for blk in blocks:
-                    out.append(Candidate("pallas4", KernelConfig(
-                        block_b=blk, ntt4_split=(n1, n2), radix=radix)))
+            for blk in blocks:
+                out.append(Candidate("pallas4", KernelConfig(
+                    block_b=blk, ntt4_split=(n1, n2))))
     fb = fallback_backend(interpret)
     dflt = Candidate(fb, default_config(op))
     if dflt not in out:
@@ -376,31 +358,25 @@ def _model_time_s(op: str, n: int, l: int, b: int, cand: Candidate,
     Memory term: each kernel reads/writes its u32[B, L, N] operands once
     (the fused kernels' whole point), so traffic is a config-independent
     ~3 x B x L x N x 4 bytes; NTT stage count scales the in-VMEM work:
-    log2 reshuffles for the flat kernel, (stages(n1)+stages(n2))/radix-
-    scaled for the 4-step.  Launch term: grid steps x LAUNCH_OVERHEAD_S —
+    log2(N) butterfly stages for both NTT kernels, plus the correction
+    multiply of the 4-step.  Launch term: grid steps x LAUNCH_OVERHEAD_S —
     what small block_b actually costs.  The model only needs to be
     *ordinally* right: anything >= PRUNE_RATIO x the best estimate is
-    skipped unmeasured.
+    skipped unmeasured.  In interpret mode no chip is modelled: the
+    memory term is left out and only launch counts rank the candidates.
     """
-    hbm_bw, _ = _roofline_constants()
     import math
 
-    bytes_main = 3 * b * l * n * 4
-    mem_s = bytes_main / hbm_bw
-    if op in NTT_OPS:
-        if cand.backend == "pallas4":
-            n1, n2 = cand.config.ntt4_split or (0, 0)
-            if not n1:
-                from repro.core.ckks import params as ckks_params
-                n1, n2 = ckks_params.ntt4_split(n)
-            stages = math.log2(n1) + math.log2(n2)
-            if cand.config.radix == 4:
-                stages = (math.ceil(math.log2(n1) / 2)
-                          + math.ceil(math.log2(n2) / 2))
-            # one extra full-tensor pass for correction + transpose
-            mem_s *= (1.0 + stages / 8.0 + 0.25)
-        else:
-            mem_s *= (1.0 + math.log2(n) / 8.0)
+    mem_s = 0.0
+    if not interpret:
+        import jax
+
+        from repro.kernels.peaks import peaks
+        hbm = peaks(jax.devices()[0].device_kind).hbm_bytes_per_s
+        mem_s = 3 * b * l * n * 4 / hbm
+        if op in NTT_OPS:
+            extra = 1.0 if cand.backend == "pallas4" else 0.0
+            mem_s *= 1.0 + (math.log2(n) + extra) / 8.0
     if cand.backend == "ref":
         # whole-tensor jnp graph: no grid, one fused dispatch
         return mem_s + LAUNCH_OVERHEAD_S

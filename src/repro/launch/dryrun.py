@@ -143,12 +143,7 @@ def _analyze(compiled, cfg, kind, tokens, n_dev, arch, shape, mesh_name,
     bytes_acc = float(ca.get("bytes accessed", 0.0))
     fused = rf.parse_memory_traffic(txt)
     roof = rf.build_roofline(cfg, kind, tokens, n_dev, flops, bytes_acc,
-                             colls, fused) if kind != "he_agg" else rf.Roofline(
-        compute_s=flops / rf.PEAK_FLOPS, memory_s=fused / rf.HBM_BW,
-        collective_s=colls.wire_bytes / rf.ICI_BW,
-        memory_upper_s=bytes_acc / rf.HBM_BW, flops=flops,
-        bytes_accessed=bytes_acc, fused_bytes=fused,
-        wire_bytes=colls.wire_bytes, model_flops=0.0, flops_ratio=0.0)
+                             colls, fused, rf.peaks(rf.DRYRUN_TARGET))
     import gzip
     os.makedirs(ARTIFACTS, exist_ok=True)
     tagsuf = f"__{tag}" if tag else ""

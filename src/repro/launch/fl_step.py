@@ -21,7 +21,6 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.ckks import encoding
@@ -113,11 +112,11 @@ def make_he_agg_step(spec: HeAggSpec, weights: list[float], mesh=None):
         def he_body(x, w, *tabs):
             return ops.apply("weighted_sum", local_tables(tabs), x, w)
 
-        he = shard_map(
+        he = jax.shard_map(
             he_body, mesh=mesh,
             in_specs=(P(None, data_axes, None, "model", None),
                       P(None, "model")) + table_specs("model"),
-            out_specs=P(data_axes, None, "model", None), check_rep=False)
+            out_specs=P(data_axes, None, "model", None), check_vma=False)
 
         def step(cts, plain):
             # [C, chunks, L, 2, N] -> limbs at axis -2 for the kernels

@@ -20,22 +20,18 @@ import numpy as np
 
 
 def _make_mesh(shape, axes, devices):
-    """jax.make_mesh across jax versions: axis_types only where supported.
+    """jax.make_mesh with Auto axes.
 
     Failures (usually a device count that cannot fill `shape`) re-raise
     with a pointer to the knob that fixes them — like every other error in
     this module, it names the README section so operators never have to
     read this source to recover."""
-    import inspect
-
     import jax
 
     try:
-        if "axis_types" in inspect.signature(jax.make_mesh).parameters:
-            return jax.make_mesh(
-                shape, axes, devices=devices,
-                axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-        return jax.make_mesh(shape, axes, devices=devices)
+        return jax.make_mesh(
+            shape, axes, devices=devices,
+            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
     except ValueError as e:
         raise RuntimeError(
             f"could not build mesh {dict(zip(axes, shape))}: {e}. "

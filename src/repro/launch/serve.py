@@ -28,6 +28,7 @@ from repro import serve
 from repro.core.ckks import cipher
 from repro.core.ckks import params as ckks_params
 from repro.core.secure_agg import ProtectedUpdate
+from repro.launch import compile_cache
 from repro.serve import sim as ssim
 from repro.wire import budget as wire_budget
 from repro.wire import stream as wire_stream
@@ -69,6 +70,7 @@ def main():
                     help="resume from the latest checkpoint in --ckpt-dir")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    compile_cache.enable()
     if args.crash_at and not args.ckpt_dir:
         ap.error("--crash-at needs --ckpt-dir (the crash leaves only the "
                  "checkpoint behind)")

@@ -1,7 +1,7 @@
 """End-to-end training driver.
 
-On CPU this runs reduced (--smoke) configs for real; the full configs are
-exercised via dryrun.py.  Includes checkpoint/restart fault tolerance: kill
+Builds the full config of --arch (its published widths); --smoke builds
+the reduced one, which is what runs on a CPU.  Includes checkpoint/restart fault tolerance: kill
 the process mid-run and re-launch — it resumes from the last checkpoint.
 
   PYTHONPATH=src python -m repro.launch.train --arch qwen1.5-0.5b --smoke \
@@ -19,17 +19,19 @@ import numpy as np
 from repro import configs
 from repro.ckpt import CheckpointManager
 from repro.data import SyntheticLM, dirichlet_partition
-from repro.launch import steps
+from repro.launch import compile_cache, steps
 from repro.launch.mesh import make_host_mesh
 from repro.models import build_model
 from repro.models.sharding import axis_env_from_mesh
 from repro.optim import AdamWConfig, adamw_init
 
 
-def main():
+def parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-0.5b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=False,
+                    help="reduced-width config (default: the full config)")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=64)
@@ -37,7 +39,12 @@ def main():
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=20)
     ap.add_argument("--log-every", type=int, default=10)
-    args = ap.parse_args()
+    return ap
+
+
+def main():
+    args = parser().parse_args()
+    compile_cache.enable()
 
     cfg = configs.get_config(args.arch, smoke=args.smoke)
     mesh = make_host_mesh()

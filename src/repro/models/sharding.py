@@ -138,14 +138,7 @@ def param_specs(params_abstract, ax: AxisEnv, mode: str = "train"):
 
 
 def _have_mesh() -> bool:
-    try:
-        m = jax.sharding.get_abstract_mesh()
-    except AttributeError:
-        # jax < 0.5 has no abstract-mesh API; the context mesh lives on the
-        # thread-local resource env instead.
-        from jax._src import mesh as _mesh
-        m = _mesh.thread_resources.env.physical_mesh
-        return m is not None and not m.empty
+    m = jax.sharding.get_abstract_mesh()
     return m is not None and not m.empty and m.shape_tuple
 
 

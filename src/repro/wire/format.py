@@ -33,6 +33,7 @@ protocol never guesses at layouts it postdates.
 """
 from __future__ import annotations
 
+import dataclasses
 import os
 import struct
 from typing import Iterator
@@ -90,6 +91,15 @@ _PLAIN_CODEC_NAMES = {v: k for k, v in _PLAIN_CODEC_IDS.items()}
 
 class WireError(ValueError):
     pass
+
+
+# What a corrupt payload can raise from the host-side parsers (struct,
+# numpy, int conversions).  These — and only these, and only around parse
+# code — become WireError.  Device work (seed expansion, unmask, folds) is
+# never inside such a conversion: a JAX runtime or compile error is a fault
+# of this server, not a malformed update, and must propagate as itself.
+PARSE_ERRORS = (struct.error, ValueError, KeyError, IndexError, TypeError,
+                OverflowError)
 
 
 class NeedMoreData(WireError):
@@ -417,7 +427,8 @@ def _parse_update(payload, ctx):
     elif ftype == T_SEEDED_CIPHERTEXT:
         if ctx is None:
             raise WireError("seeded ciphertext needs a ctx to expand")
-        ct = _parse_seeded_ciphertext(ct_payload, ct_version).expand(ctx)
+        # deserialize expands it, outside the parse-error conversion
+        ct = _parse_seeded_ciphertext(ct_payload, ct_version)
     else:
         raise WireError(f"unexpected inner frame type {ftype}")
     ftype, _, pl_payload, _ = parse_frame(payload, off)
@@ -513,11 +524,38 @@ def deserialize(buf, ctx=None, off: int = 0):
     if parser is None:
         raise WireError(f"no parser for frame type {ftype:#x}")
     try:
-        return parser(payload, ctx, version), end
+        out = parser(payload, ctx, version)
     except WireError:
         raise
-    except Exception as e:
+    except PARSE_ERRORS as e:
         # struct.error / KeyError / reshape ValueError etc. from a payload
         # whose bytes were mutated after the envelope survived
         raise WireError(
             f"malformed frame type {ftype:#x} payload: {e!r}") from e
+    if ftype == T_PROTECTED_UPDATE and not isinstance(out.ct, Ciphertext):
+        check_seeded(out.ct, ctx)
+        out = dataclasses.replace(out, ct=out.ct.expand(ctx))
+    return out, end
+
+
+def check_seeded(sct, ctx) -> None:
+    """Validate a parsed seeded ciphertext against `ctx` before its `a`
+    rows are expanded on the device, so a wire-mutated shape, seed or
+    chunk offset is rejected here as WireError and never reaches a device
+    graph."""
+    c0 = np.asarray(sct.c0)
+    if (c0.ndim != 3 or c0.dtype != np.uint32
+            or c0.shape[2] != ctx.n_poly
+            or not 1 <= c0.shape[1] <= ctx.n_limbs):
+        raise WireError(
+            f"seeded ciphertext c0 {c0.dtype}{list(c0.shape)} does not fit "
+            f"this context's [chunks, <= {ctx.n_limbs} limbs, "
+            f"{ctx.n_poly}]")
+    if not 0 <= int(sct.seed) < 1 << 63:
+        # PRNGKey takes a signed 64-bit seed; no conforming sender emits
+        # a larger one
+        raise WireError(f"seeded ciphertext seed {sct.seed} exceeds 2^63")
+    if not 0 <= int(sct.chunk_offset) <= (1 << 31) - c0.shape[0]:
+        # chunk ids are int32 in the expansion graph
+        raise WireError(f"seeded ciphertext chunks [{sct.chunk_offset}, "
+                        f"+{c0.shape[0]}) exceed 2^31")

@@ -32,6 +32,7 @@ in-memory path — flush batching does not change a single bit.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import itertools
@@ -190,6 +191,29 @@ def peek_update_meta(blob: bytes) -> UpdateMeta:
 # ---------------------------------------------------------------------------
 
 
+@contextlib.contextmanager
+def _parsing():
+    """Convert what a corrupt payload raises from host-side parse code
+    into WireError — wrap parse code only, never device work."""
+    try:
+        yield
+    except wf.WireError:
+        raise
+    except wf.PARSE_ERRORS as e:
+        raise wf.WireError(f"malformed update stream: {e!r}") from e
+
+
+def _frames(blob):
+    """wf.iter_frames with each frame's parse under _parsing()."""
+    it = wf.iter_frames(blob)
+    while True:
+        with _parsing():
+            item = next(it, None)
+        if item is None:
+            return
+        yield item
+
+
 @functools.partial(jax.jit, static_argnames=("ctx", "token"))
 def _accum_chunks_graph(ctx: CkksContext, token, accs, cts, w_mont):
     """One chunk-batched fold: acc[k] + w[k] (*) ct[k] for every ready row,
@@ -316,7 +340,6 @@ class StreamIngest:
             self._in_scale = float(scale)
         elif abs(self._in_scale - scale) > 1e-6 * self._in_scale:
             raise wf.WireError("mixed ciphertext scales in one aggregation")
-        data = np.asarray(data)
         if data.dtype != np.uint32:
             raise wf.WireError(
                 f"ciphertext chunk dtype {data.dtype} is not uint32")
@@ -383,11 +406,16 @@ class StreamIngest:
                 f"derive={mc.derive}) do not match the provisioned "
                 f"materials (a_seed={sm.a_seed}, derive={sm.derive}) for "
                 f"client {meta.cid} round {meta.round}")
+        masked = np.asarray(mc.masked)
+        if masked.ndim != 2 or masked.shape[1] != self.ctx.n_poly:
+            raise wf.WireError(
+                f"masked chunk rows {list(masked.shape)} are not "
+                f"[rows, {self.ctx.n_poly}]")
         try:
-            return _tc.server_unmask(self.ctx, sm, mc.masked,
-                                     int(mc.chunk_offset))
+            _tc.check_unmask_rows(sm, int(mc.chunk_offset), masked.shape[0])
         except ValueError as e:
             raise wf.WireError(f"transcipher unmask failed: {e}") from e
+        return _tc.server_unmask(self.ctx, sm, masked, int(mc.chunk_offset))
 
     def _fold_plain_decoded(self, plain: np.ndarray, weight: float) -> None:
         if self._acc_plain is None:
@@ -437,10 +465,11 @@ class StreamIngest:
         prev_in_scale = self._in_scale
         acc_was_uninit = self._acc_ct is None
         try:
-            for ftype, _, payload in wf.iter_frames(blob):
+            for ftype, _, payload in _frames(blob):
                 if ftype == wf.T_UPDATE_BEGIN:
-                    cid, n_samples, rnd, n_chunks, kind = _BEGIN.unpack_from(
-                        payload, 0)
+                    with _parsing():
+                        cid, n_samples, rnd, n_chunks, kind = \
+                            _BEGIN.unpack_from(payload, 0)
                     if kind not in _CT_KINDS:
                         raise wf.WireError(
                             f"unknown ct_kind {kind} in UPDATE_BEGIN; this "
@@ -451,7 +480,8 @@ class StreamIngest:
                 elif ftype == wf.T_CT_CHUNK:
                     if meta is None:
                         raise wf.WireError("CT_CHUNK before UPDATE_BEGIN")
-                    (chunk_idx,) = struct.unpack_from("<I", payload, 0)
+                    with _parsing():
+                        (chunk_idx,) = struct.unpack_from("<I", payload, 0)
                     if chunk_idx >= meta.n_chunks:
                         raise wf.WireError(
                             f"chunk index {chunk_idx} >= declared "
@@ -479,6 +509,7 @@ class StreamIngest:
                     if isinstance(inner, _c.MaskedChunk):
                         inner = self._unmask_chunk(meta, inner)
                     elif isinstance(inner, _c.SeededCiphertext):
+                        wf.check_seeded(inner, self.ctx)
                         inner = inner.expand(self.ctx)
                     self._buffer_chunk(chunk_idx, inner.data, inner.scale,
                                        w_mont)
@@ -507,8 +538,9 @@ class StreamIngest:
                     # a wire-mutated dim must reject the whole update here;
                     # the fold after validation then cannot fail, so the
                     # success path needs no accumulator snapshot
-                    plain = _c.dequantize_plain(
-                        *wf._parse_plain_segment(payload))
+                    with _parsing():
+                        plain = _c.dequantize_plain(
+                            *wf._parse_plain_segment(payload))
                     ref_shape = (self._acc_plain.shape
                                  if self._acc_plain is not None
                                  else plain_segments[0].shape
@@ -530,9 +562,11 @@ class StreamIngest:
                     f"update declared {meta.n_chunks} chunks, "
                     f"received {len(chunks_seen)}")
         except Exception as e:
-            # rejected update: NOTHING of it may reach the accumulator —
-            # drop its queued chunks and roll back any state its chunks
-            # initialized (struct.error etc. count as rejections too)
+            # NOTHING of a failed update may reach the accumulator — drop
+            # its queued chunks and roll back any state its chunks
+            # initialized, whatever the failure.  Only a WireError is a
+            # rejected update; anything else (a JAX runtime or compile
+            # error in expand/unmask/fold) propagates as itself.
             if n_buffered:
                 del self._pending[len(self._pending) - n_buffered:]
                 self._note_decoded(-n_buffered)
@@ -548,13 +582,9 @@ class StreamIngest:
             if acc_was_uninit:
                 # the rejected chunks must not pin the limb/poly dims either
                 self._acc_ct = None
-            self._m_rejected.inc()
             if isinstance(e, wf.WireError):
-                raise
-            # uniform rejection contract (fuzzed in tests/test_wire.py):
-            # corrupt payloads that slip past the frame envelope surface as
-            # WireError here, never as a raw struct/numpy error
-            raise wf.WireError(f"malformed update stream: {e!r}") from e
+                self._m_rejected.inc()
+            raise
         # validated above: these folds cannot fail, so no rollback is needed
         # past this point (and no per-ingest accumulator snapshot either)
         for plain in plain_segments:

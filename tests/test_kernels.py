@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core.ckks import params as ckks_params
-from repro.kernels import he_agg, ntt, ops, pointwise, ref
+from repro.kernels import he_agg, lift, ntt, ops, pointwise, ref
 
 import gold
 
@@ -169,3 +169,37 @@ def test_ops_backend_dispatch_consistent():
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     finally:
         ops.set_backend(old)
+
+
+def test_pallas_accum_chunks_split_launches_exact(monkeypatch):
+    """A weight table larger than the SMEM budget runs as several launches
+    of whole rows, bit-identical to the oracle."""
+    from repro.kernels import tiling
+
+    monkeypatch.setattr(tiling, "SMEM_WORDS", 8)   # 4 rows per launch
+    ctx = ckks_params.make_test_context(n_poly=128, n_limbs=2)
+    t = ctx.tables
+    rng = np.random.RandomState(10)
+    acc = _rand_limbed(rng, ctx, (7, 2))
+    cts = _rand_limbed(rng, ctx, (7, 2))
+    w = jnp.asarray(rng.randint(1, min(ctx.primes), size=(7, 2))
+                    .astype(np.uint32))
+    a = he_agg.he_weighted_accum_chunks_fused(acc, cts, w, t.qs,
+                                              t.qinv_negs, interpret=True,
+                                              block_k=2)
+    b = ref.he_weighted_accum_chunks_fused(acc, cts, w, t.qs, t.qinv_negs)
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("fn", [
+    ntt.ntt_fwd_fused, ntt.ntt_inv_fused, ntt.ntt4_fwd_fused,
+    ntt.ntt4_inv_fused, pointwise.mul_add_fused,
+    he_agg.he_weighted_sum_fused, he_agg.he_weighted_accum_fused,
+    he_agg.he_weighted_accum_chunks_fused, lift.mod_lift_fused])
+def test_kernel_entry_points_take_interpret_explicitly(fn):
+    """No Pallas entry point may default to interpret mode: a caller that
+    forgot the flag would run the Python interpreter on a TPU."""
+    import inspect
+
+    p = inspect.signature(fn).parameters["interpret"]
+    assert p.default is inspect.Parameter.empty, fn.__name__

@@ -12,7 +12,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.ckks import params as ckks_params
@@ -97,10 +96,10 @@ def _sharded_ntt(ctx, mesh, x, op):
     def body(x, *tabs):
         return ops.apply(op, sh.local_tables(tabs), x)
 
-    f = shard_map(body, mesh=mesh,
+    f = jax.shard_map(body, mesh=mesh,
                   in_specs=(P("data", "model", None),)
                   + sh.table_specs("model"),
-                  out_specs=P("data", "model", None), check_rep=False)
+                  out_specs=P("data", "model", None), check_vma=False)
     return f(x, *sh.table_arrays(ctx.tables))
 
 

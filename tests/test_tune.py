@@ -3,7 +3,7 @@
 Four contracts:
 
   1. **Bit-exactness across the FULL swept grid** — every candidate the
-     tuner can ever pick (backend x block_b x ntt4_split x radix) must
+     tuner can ever pick (backend x block_b x ntt4_split) must
      reproduce the checked-in gold KATs exactly.  Tuning may only change
      launch geometry, never bits.
   2. **Cache round-trip** — save -> load resolves to the same
@@ -85,7 +85,7 @@ def _inputs(op, ctx, b, seed=7):
 @pytest.mark.parametrize("ctx_name", sorted(gold.KAT_CONTEXTS))
 @pytest.mark.parametrize("op", tune.NTT_OPS)
 def test_full_ntt_grid_reproduces_golden_kats(ctx_name, op):
-    """Every (backend, block_b, ntt4_split, radix) candidate reproduces
+    """Every (backend, block_b, ntt4_split) candidate reproduces
     the golden NTT vectors bit-exactly — the tuner cannot pick a config
     that changes ciphertext bits."""
     golden = gold.load_kats()[f"{ctx_name}/{op}"]
@@ -97,8 +97,7 @@ def test_full_ntt_grid_reproduces_golden_kats(ctx_name, op):
     # the grid really is the full cross product, not a truncation
     splits = ckks_params.ntt4_split_candidates(ctx.n_poly)
     blocks = [blk for blk in tune.BLOCK_CANDIDATES if blk <= 2]
-    assert len(cands) >= 1 + len(blocks) * (
-        1 + len(splits) * len(tune.RADIX_CANDIDATES))
+    assert len(cands) >= 1 + len(blocks) * (1 + len(splits))
     for cand in cands:
         got = np.asarray(ops.run_config(op, cand.backend, cand.config, t, x))
         np.testing.assert_array_equal(
@@ -131,7 +130,7 @@ def test_cache_round_trip(tmp_path):
     import jax
 
     platform = jax.default_backend()
-    cfg = tune.KernelConfig(block_b=2, ntt4_split=(16, 4), radix=4)
+    cfg = tune.KernelConfig(block_b=2, ntt4_split=(16, 4))
     tune.put("ntt_fwd", 64, 2, 5, platform, "pallas4", cfg,
              tuned_ms=1.0, default_ms=2.0)
     tune.put("mul_add", 64, 2, 5, platform, "pallas",
@@ -208,8 +207,7 @@ def test_auto_dispatch_resolves_from_cache():
     np.testing.assert_array_equal(np.asarray(ops.ntt_fwd(x, ctx)), want)
     # hit -> the cached pallas4 variant config, still bit-exact
     tune.put("ntt_fwd", ctx.n_poly, ctx.n_limbs, 5, jax.default_backend(),
-             "pallas4", tune.KernelConfig(block_b=2, ntt4_split=(16, 4),
-                                          radix=4))
+             "pallas4", tune.KernelConfig(block_b=2, ntt4_split=(16, 4)))
     np.testing.assert_array_equal(np.asarray(ops.ntt_fwd(x, ctx)), want)
 
 
@@ -287,7 +285,7 @@ def test_ragged_batch_every_op(op):
     if op in tune.NTT_OPS:
         got = np.asarray(ops.run_config(
             op, "pallas4",
-            tune.KernelConfig(block_b=2, ntt4_split=(16, 4), radix=4),
+            tune.KernelConfig(block_b=2, ntt4_split=(16, 4)),
             t, *args))
         np.testing.assert_array_equal(got, want)
 

@@ -525,6 +525,36 @@ def test_stream_corrupt_chunk_payload_drops_buffered_chunks():
     assert ing.peak_chunk_buffers <= agg.part.n_chunks
 
 
+def test_stream_device_error_propagates_not_rejected(monkeypatch):
+    """A JAX runtime error while a chunk is expanded on the device is a
+    fault of this server, not a malformed update: it propagates as itself,
+    is not counted as a wire rejection, and leaves nothing of the update in
+    the accumulator.  A corrupt blob still raises WireError."""
+    agg, m = make_agg()
+    v = agg.client_protect_seeded(m, SK, jax.random.PRNGKey(1), a_seed=5)
+    blob = ws.pack_update_frames(v, cid=0, n_samples=1,
+                                 seeded=wc.seed_compress(v.ct, 5))
+    ing = ws.StreamIngest(CTX)
+
+    def device_fault(*a, **k):
+        raise jax.errors.JaxRuntimeError("INTERNAL: simulated device fault")
+
+    with monkeypatch.context() as mp:
+        mp.setattr(cipher, "expand_a_rows", device_fault)
+        with pytest.raises(jax.errors.JaxRuntimeError):
+            ing.ingest(blob, 1.0)
+    assert ing.rejected_updates == 0
+    assert ing._acc_ct is None and not ing._pending
+    with pytest.raises(wf.WireError):
+        ing.ingest(blob[:len(blob) - wf.HEADER_BYTES], 1.0)
+    assert ing.rejected_updates == 1
+    ing.ingest(blob, 1.0)                # the same update, healthy device
+    clean = ws.StreamIngest(CTX)
+    clean.ingest(blob, 1.0)
+    np.testing.assert_array_equal(np.asarray(ing.finalize().ct.data),
+                                  np.asarray(clean.finalize().ct.data))
+
+
 # ---------------------------------------------------------------------------
 # decoder fuzzing: any mutation/truncation -> WireError, never a crash,
 # hang, or over-read.  The deterministic sweeps below run in every

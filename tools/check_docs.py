@@ -189,8 +189,6 @@ def render_bench_tables() -> str:
         bits = [f"block {cfg['block_b']}"]
         if cfg.get("ntt4_split"):
             bits.append(f"{cfg['ntt4_split'][0]}x{cfg['ntt4_split'][1]}")
-        if cfg.get("radix", 2) != 2:
-            bits.append(f"radix {cfg['radix']}")
         out.append(
             f"| {r['op']} | {r['n']} | {r['l']} | {r['b']} | "
             f"{r['backend']} | {', '.join(bits)} | "
@@ -401,7 +399,7 @@ def check_tune_docs() -> list[str]:
     if not sec:
         errors.append("DESIGN.md: missing the '## §12' autotuner section")
         return errors
-    for needed in ("block_b", "ntt4_split", "radix", "shape key",
+    for needed in ("block_b", "ntt4_split", "shape key",
                    "PRUNE_RATIO", "launch geometry"):
         if needed not in sec.group(0):
             errors.append(f"DESIGN.md §12: autotuner section no longer "
