@@ -16,7 +16,6 @@ import dataclasses
 
 import numpy as np
 
-from repro import obs
 from repro.core.secure_agg import ProtectedUpdate, SelectiveHEAggregator
 from repro.wire import budget as wire_budget
 from repro.wire import stream as wire_stream
@@ -92,9 +91,7 @@ class FLServer:
                                         direction=wire_budget.UPLINK)
         self.last_ingest = ingest
         self.rounds_aggregated += 1
-        with obs.span("wire.finalize", n_updates=len(blobs),
-                      launches=ingest.accum_launches):
-            return ingest.finalize()
+        return ingest.finalize()
 
     # -- async (FedBuff) -----------------------------------------------------
 

@@ -10,9 +10,11 @@ The measurement substrate under every performance claim this repo makes
     `peak_chunk_buffers`, the `wire/budget.py` byte ledger) now resolve
     here behind compatible properties.
   * **trace spans** (`repro/obs/trace.py`) — nestable `span()` context
-    managers emitting Chrome-trace-event JSONL loadable in Perfetto,
-    wired through the FL round loop, the wire ingest/flush path, and the
-    sharded HE dispatches.  Gated on REPRO_OBS=1.
+    managers wired through the FL round loop, the wire ingest/flush and
+    pack paths, and the serving loop.  Each is always a `repro.<name>`
+    profiler annotation, so a profiler session (the chip benchmark's
+    traced runs) sees it on its own clock; with REPRO_OBS=1 it also
+    emits Chrome-trace-event JSONL loadable in Perfetto.
   * **kernel hooks** (`repro/obs/hooks.py`) — per-op wall time +
     `jax.profiler.TraceAnnotation` / `jax.named_scope` in the
     `kernels/ops.py` registry, and `kernel_launch` timing for jitted HE
@@ -24,22 +26,22 @@ phase/bytes/launches table from a trace file).  `provenance()` stamps
 BENCH_*.json entries with backend token / device kind / obs version.
 
 Environment (canonical table: README.md):
-  REPRO_OBS=1           enable spans + kernel hooks (default off).
+  REPRO_OBS=1           enable the JSONL span file + kernel hooks
+                        (default off; profiler annotations are always on).
   REPRO_OBS_TRACE=path  trace sink (default ./obs_trace.jsonl).
 """
 from __future__ import annotations
 
 from repro.obs.metrics import (REGISTRY, Counter, Gauge, Histogram,
                                MetricsRegistry)
-from repro.obs.trace import (NULL_SPAN, OBS_VERSION, Span, Tracer, configure,
-                             enabled, event, flush, get_tracer, span,
-                             trace_path)
+from repro.obs.trace import (OBS_VERSION, Span, Tracer, configure, enabled,
+                             event, flush, get_tracer, span, trace_path)
 from repro.obs.hooks import (kernel_hooks_enabled, kernel_launch,
                              maybe_block, timed_kernel)
 
 __all__ = [
     "REGISTRY", "Counter", "Gauge", "Histogram", "MetricsRegistry",
-    "NULL_SPAN", "OBS_VERSION", "Span", "Tracer",
+    "OBS_VERSION", "Span", "Tracer",
     "configure", "enabled", "event", "flush", "get_tracer", "span",
     "trace_path",
     "kernel_hooks_enabled", "kernel_launch", "maybe_block", "timed_kernel",

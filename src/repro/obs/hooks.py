@@ -63,10 +63,6 @@ def timed_kernel(op: str, backend: str, token, impl, *args, config=None):
     with jax.profiler.TraceAnnotation(f"he.{op}"):
         out = jax.block_until_ready(impl(*args))
     dt = time.perf_counter() - t0
-    _metrics.REGISTRY.counter("kernel_op_launches_total", op=op,
-                              backend=backend).inc()
-    _metrics.REGISTRY.histogram("kernel_op_seconds", op=op,
-                                backend=backend).observe(dt)
     span_args = {"op": op, "backend": backend, "token": str(token),
                  "eager": True}
     if config is not None:
@@ -77,7 +73,7 @@ def timed_kernel(op: str, backend: str, token, impl, *args, config=None):
 
 
 class _KernelLaunch:
-    """Span + histogram around one jitted-graph launch (blocks on exit)."""
+    """Kernel event around one jitted-graph launch (blocks on exit)."""
 
     __slots__ = ("op", "token", "args", "_ts0", "_t0", "_out")
 
@@ -103,11 +99,6 @@ class _KernelLaunch:
         if self._out is not None and exc_type is None:
             jax.block_until_ready(self._out)
         dt = time.perf_counter() - self._t0
-        backend = self.args.get("backend", "")
-        _metrics.REGISTRY.counter("kernel_launches_total", op=self.op,
-                                  backend=backend).inc()
-        _metrics.REGISTRY.histogram("kernel_launch_seconds", op=self.op,
-                                    backend=backend).observe(dt)
         _trace.get_tracer().emit_complete(
             f"he.{self.op}", self._ts0, dt * 1e6, cat="kernel",
             args={"op": self.op, "token": str(self.token), **self.args})
@@ -138,8 +129,8 @@ def kernel_launch(op: str, token, **args):
             out = kl.done(jitted_graph(...))
 
     `kl.done(out)` registers the outputs; exit blocks on them and records
-    wall time into the `kernel_launch_seconds` histogram and a cat="kernel"
-    trace event keyed by the backend token.  No-op when obs is disabled.
+    the wall time as a cat="kernel" trace event keyed by the backend token.
+    No-op when obs is disabled.
     """
     if not _trace.enabled():
         return _NULL_LAUNCH

@@ -1,7 +1,16 @@
-"""Nestable trace spans emitting Chrome-trace-event JSONL.
+"""Nestable trace spans: profiler annotations, and Chrome-trace-event JSONL.
 
-`span("round", round=3)` is a context manager that records one Chrome
-trace "complete" event (`ph: "X"`) with microsecond `ts`/`dur` on exit.
+`span("round", round=3)` is a context manager with two outputs:
+
+  * always, a `jax.profiler.TraceAnnotation("repro.round")` — an inactive
+    TraceMe unless a profiler session records, in which case the span lands
+    in the profiler trace on the profiler's clock beside the device events.
+    While a session records, the span also adds its seconds to the registry
+    histogram `obs_span_seconds{span=<name>}`, the same split read in
+    process without parsing the trace;
+  * with REPRO_OBS=1, one Chrome trace "complete" event (`ph: "X"`) with
+    microsecond `ts`/`dur`, written on exit.
+
 Spans nest by wall-time containment on the emitting thread — exactly the
 model Perfetto / chrome://tracing render — so the per-round span tree in
 `fl/orchestrator.py` (round > client > local_train/encrypt, round >
@@ -16,10 +25,12 @@ load directly, while staying trivially parseable line-by-line
 mid-run loses at most the open spans.
 
 Gating: `enabled()` is False unless REPRO_OBS=1 (or `configure()` flips
-it), and a disabled `span()` returns a shared no-op — the round loop pays
-one truthiness check per span and nothing else (overhead policy, DESIGN.md
-§11.3).  The default sink is $REPRO_OBS_TRACE (default ./obs_trace.jsonl),
-opened lazily on the first event.
+it).  Disabled, a span writes no JSONL event and blocks on nothing; its
+whole cost is one inactive TraceMe (overhead policy, DESIGN.md §11.3).
+jax is imported on the first span, so jax-free readers of this package
+(tools/round_report.py) never load it.  The default sink is
+$REPRO_OBS_TRACE (default ./obs_trace.jsonl), opened lazily on the first
+event.
 """
 from __future__ import annotations
 
@@ -28,6 +39,8 @@ import json
 import os
 import threading
 import time
+
+from repro.obs import metrics as _metrics
 
 #: schema version stamped into trace metadata and BENCH provenance
 OBS_VERSION = 1
@@ -38,6 +51,8 @@ _ENV_TRACE_PATH = os.environ.get("REPRO_OBS_TRACE", "obs_trace.jsonl")
 _enabled = _ENV_ENABLED
 _tracer: "Tracer | None" = None
 _lock = threading.Lock()
+# jax.profiler.TraceAnnotation, imported on the first span
+_Annotation = None
 
 
 def enabled() -> bool:
@@ -159,69 +174,79 @@ class Tracer:
                 self._fh = None
 
 
+def _annotation(name: str, args: dict):
+    """The profiler annotation `repro.<name>` carrying the span's entry
+    args."""
+    global _Annotation
+    if _Annotation is None:
+        import jax.profiler
+
+        _Annotation = jax.profiler.TraceAnnotation
+    return _Annotation("repro." + name, **args)
+
+
 class Span:
-    """One nestable trace span; records a complete event on __exit__."""
+    """One nestable span: a profiler annotation, and with a tracer a
+    complete JSONL event recorded on __exit__."""
 
-    __slots__ = ("tracer", "name", "cat", "args", "_ts0")
+    __slots__ = ("tracer", "name", "cat", "args", "_ann", "_ts0", "_t0")
 
-    def __init__(self, tracer: Tracer, name: str, cat: str, args: dict):
+    def __init__(self, tracer: Tracer | None, name: str, cat: str,
+                 args: dict):
         self.tracer = tracer
         self.name = name
         self.cat = cat
         self.args = args
+        self._ann = _annotation(name, args)
         self._ts0 = 0.0
+        self._t0 = None
 
     def set(self, **kw) -> None:
         """Attach/overwrite args after the span opened (e.g. byte counts
-        known only at the end of the phase)."""
+        known only at the end of the phase); they reach the JSONL event
+        only, the annotation having taken its args at entry."""
         self.args.update(kw)
 
     def __enter__(self) -> "Span":
-        self.tracer._stack().append(self)
-        self._ts0 = self.tracer.now_us()
+        self._ann.__enter__()
+        if _Annotation.is_enabled():      # a profiler session records
+            self._t0 = time.perf_counter()
+        if self.tracer is not None:
+            self.tracer._stack().append(self)
+            self._ts0 = self.tracer.now_us()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
-        dur = self.tracer.now_us() - self._ts0
-        st = self.tracer._stack()
-        if st and st[-1] is self:
-            st.pop()
-        if exc_type is not None:
-            self.args["error"] = exc_type.__name__
-        self.tracer.emit_complete(self.name, self._ts0, dur, cat=self.cat,
-                                  args=self.args)
-
-
-class _NullSpan:
-    """Shared no-op span: the entire disabled-path cost of obs.span()."""
-
-    __slots__ = ()
-
-    def set(self, **kw) -> None:
-        pass
-
-    def __enter__(self) -> "_NullSpan":
-        return self
-
-    def __exit__(self, exc_type, exc, tb) -> None:
-        pass
-
-
-NULL_SPAN = _NullSpan()
+        if self.tracer is not None:
+            dur = self.tracer.now_us() - self._ts0
+            st = self.tracer._stack()
+            if st and st[-1] is self:
+                st.pop()
+            if exc_type is not None:
+                self.args["error"] = exc_type.__name__
+            self.tracer.emit_complete(self.name, self._ts0, dur,
+                                      cat=self.cat, args=self.args)
+        if self._t0 is not None:
+            _metrics.REGISTRY.histogram("obs_span_seconds",
+                                        span=self.name).observe(
+                time.perf_counter() - self._t0)
+        self._ann.__exit__(exc_type, exc, tb)
 
 
 def span(name: str, cat: str = "phase", **args):
-    """Open a nestable trace span (no-op unless obs is enabled).
+    """Open a nestable span: a `repro.<name>` profiler annotation always,
+    a JSONL event too when obs is enabled.
 
     Usage::
 
         with obs.span("round", round=rnd) as sp:
             ...
             sp.set(bytes_up=ledger.total(UPLINK, rnd))
+
+    Pass as entry args only values that are cheap and known at entry
+    (they go into the annotation); spans opened per chunk take none.
     """
-    if not _enabled:
-        return NULL_SPAN
-    return Span(get_tracer(), name, cat, dict(args))
+    return Span(get_tracer() if _enabled else None, name, cat, args)
 
 
 def event(name: str, cat: str = "event", **args) -> None:

@@ -104,29 +104,40 @@ def pack_update_frames(upd: ProtectedUpdate, *, cid: int, n_samples: int,
         UPDATE_END, each a length-prefixed wire frame (DESIGN.md §6.1,
         §9.2 for the v2 layout diff).
     """
-    n_chunks = int(upd.ct.data.shape[0])
-    kind = CT_SEEDED if seeded is not None else CT_FULL
-    out = [wf.frame(wf.T_UPDATE_BEGIN,
-                    _BEGIN.pack(cid, n_samples, rnd, n_chunks, kind),
-                    version=version)]
-    ct_host = np.asarray(seeded.c0 if seeded is not None else upd.ct.data)
-    for b in range(n_chunks):
-        if seeded is not None:
-            chunk = _c.SeededCiphertext(c0=ct_host[b:b + 1],
-                                        seed=seeded.seed, scale=seeded.scale,
-                                        chunk_offset=b,
-                                        derive=seeded.derive)
-            inner = wf.serialize_seeded_ciphertext(chunk, version=version)
-        else:
-            inner = wf.serialize_ciphertext(Ciphertext(
-                data=ct_host[b:b + 1], scale=upd.ct.scale), version=version)
-        out.append(wf.frame(wf.T_CT_CHUNK, struct.pack("<I", b) + inner,
-                            version=version))
-    arr, qscale = _c.quantize_plain(np.asarray(upd.plain), plain_codec)
-    out.append(wf.serialize_plain_segment(arr, plain_codec, qscale,
-                                          version=version))
-    out.append(wf.frame(wf.T_UPDATE_END, b"", version=version))
-    return b"".join(out)
+    with obs.span("wire.pack", cid=cid):
+        n_chunks = int(upd.ct.data.shape[0])
+        kind = CT_SEEDED if seeded is not None else CT_FULL
+        out = [wf.frame(wf.T_UPDATE_BEGIN,
+                        _BEGIN.pack(cid, n_samples, rnd, n_chunks, kind),
+                        version=version)]
+        # the update's two device arrays, fetched to the host once
+        with obs.span("wire.pack.fetch"):
+            ct_host = np.asarray(seeded.c0 if seeded is not None
+                                 else upd.ct.data)
+            plain_host = np.asarray(upd.plain)
+        with obs.span("wire.pack.ct_frames"):
+            for b in range(n_chunks):
+                if seeded is not None:
+                    chunk = _c.SeededCiphertext(
+                        c0=ct_host[b:b + 1], seed=seeded.seed,
+                        scale=seeded.scale, chunk_offset=b,
+                        derive=seeded.derive)
+                    inner = wf.serialize_seeded_ciphertext(chunk,
+                                                           version=version)
+                else:
+                    inner = wf.serialize_ciphertext(Ciphertext(
+                        data=ct_host[b:b + 1], scale=upd.ct.scale),
+                        version=version)
+                out.append(wf.frame(wf.T_CT_CHUNK,
+                                    struct.pack("<I", b) + inner,
+                                    version=version))
+        with obs.span("wire.pack.plain"):
+            arr, qscale = _c.quantize_plain(plain_host, plain_codec)
+            out.append(wf.serialize_plain_segment(arr, plain_codec, qscale,
+                                                  version=version))
+        out.append(wf.frame(wf.T_UPDATE_END, b"", version=version))
+        with obs.span("wire.pack.join"):
+            return b"".join(out)
 
 
 def pack_masked_update_frames(masked: _c.MaskedChunk,
@@ -201,17 +212,6 @@ def _parsing():
         raise
     except wf.PARSE_ERRORS as e:
         raise wf.WireError(f"malformed update stream: {e!r}") from e
-
-
-def _frames(blob):
-    """wf.iter_frames with each frame's parse under _parsing()."""
-    it = wf.iter_frames(blob)
-    while True:
-        with _parsing():
-            item = next(it, None)
-        if item is None:
-            return
-        yield item
 
 
 @functools.partial(jax.jit, static_argnames=("ctx", "token"))
@@ -359,34 +359,36 @@ class StreamIngest:
         """Fold every ready chunk into the accumulator — ONE chunk-batched
         accumulate launch per pass (a second pass only happens if the same
         chunk index was buffered twice, to preserve arrival order)."""
-        while self._pending:
-            batch, rest, seen = [], [], set()
-            for item in self._pending:
-                if item[0] in seen:
-                    rest.append(item)
-                else:
-                    seen.add(item[0])
-                    batch.append(item)
-            self._pending = rest
-            idxs = [i for i, _, _ in batch]
-            cts = jnp.stack([x for _, x, _ in batch])          # [K, 2, L, N]
-            ws = jnp.stack([w for _, _, w in batch])           # [K, L]
-            zero = jnp.zeros((2, self._n_limbs, self._n), dtype=jnp.uint32)
-            accs = jnp.stack([self._acc_ct.get(i, zero) for i in idxs])
-            token = ops.backend_token()
-            with obs.kernel_launch("weighted_accum_chunks", token,
-                                   rows=len(batch),
-                                   sharded=self.sharded is not None) as kl:
-                if self.sharded is not None:
-                    out = kl.done(
-                        self.sharded.weighted_accum_chunks(accs, cts, ws))
-                else:
-                    out = kl.done(_accum_chunks_graph(self.ctx, token,
-                                                      accs, cts, ws))
-            self._m_launches.inc()
-            for j, i in enumerate(idxs):
-                self._acc_ct[i] = out[j]
-            self._note_decoded(-len(batch))
+        with obs.span("wire.flush"):
+            while self._pending:
+                batch, rest, seen = [], [], set()
+                for item in self._pending:
+                    if item[0] in seen:
+                        rest.append(item)
+                    else:
+                        seen.add(item[0])
+                        batch.append(item)
+                self._pending = rest
+                idxs = [i for i, _, _ in batch]
+                cts = jnp.stack([x for _, x, _ in batch])    # [K, 2, L, N]
+                ws = jnp.stack([w for _, _, w in batch])     # [K, L]
+                zero = jnp.zeros((2, self._n_limbs, self._n),
+                                 dtype=jnp.uint32)
+                accs = jnp.stack([self._acc_ct.get(i, zero) for i in idxs])
+                token = ops.backend_token()
+                with obs.kernel_launch(
+                        "weighted_accum_chunks", token, rows=len(batch),
+                        sharded=self.sharded is not None) as kl:
+                    if self.sharded is not None:
+                        out = kl.done(self.sharded.weighted_accum_chunks(
+                            accs, cts, ws))
+                    else:
+                        out = kl.done(_accum_chunks_graph(self.ctx, token,
+                                                          accs, cts, ws))
+                self._m_launches.inc()
+                for j, i in enumerate(idxs):
+                    self._acc_ct[i] = out[j]
+                self._note_decoded(-len(batch))
 
     def _unmask_chunk(self, meta: UpdateMeta, mc: _c.MaskedChunk):
         """Transcipher one arriving masked chunk into its seeded-equivalent
@@ -431,6 +433,67 @@ class StreamIngest:
         self._fold_plain_decoded(_c.dequantize_plain(arr, codec, qscale),
                                  weight)
 
+    def _parse_begin(self, payload) -> UpdateMeta:
+        with _parsing():
+            cid, n_samples, rnd, n_chunks, kind = _BEGIN.unpack_from(payload,
+                                                                     0)
+        if kind not in _CT_KINDS:
+            raise wf.WireError(f"unknown ct_kind {kind} in UPDATE_BEGIN; "
+                               f"this build implements {_CT_KINDS}")
+        return UpdateMeta(cid, n_samples, rnd, n_chunks, kind == CT_SEEDED,
+                          kind == CT_TRANSCIPHER)
+
+    def _parse_chunk(self, payload, meta: UpdateMeta | None,
+                     chunks_seen: set):
+        """One CT_CHUNK frame -> (chunk index, its nested payload), the
+        index checked against the header and the chunks seen so far."""
+        if meta is None:
+            raise wf.WireError("CT_CHUNK before UPDATE_BEGIN")
+        with _parsing():
+            (chunk_idx,) = struct.unpack_from("<I", payload, 0)
+        if chunk_idx >= meta.n_chunks:
+            raise wf.WireError(f"chunk index {chunk_idx} >= declared "
+                               f"n_chunks {meta.n_chunks}")
+        if chunk_idx in chunks_seen:
+            raise wf.WireError(f"duplicate chunk {chunk_idx}")
+        chunks_seen.add(chunk_idx)
+        inner, _ = wf.deserialize(payload, self.ctx, off=4)
+        # the nested payload kind must MATCH the declared ct_kind:
+        # dispatching on isinstance alone would let a masked chunk slip
+        # into a seeded/full update (or vice versa), misclassifying
+        # UpdateMeta and the ledger — a wire-consistency violation,
+        # rejected atomically
+        got = ("masked" if isinstance(inner, _c.MaskedChunk)
+               else "seeded" if isinstance(inner, _c.SeededCiphertext)
+               else "full")
+        want = ("masked" if meta.transcipher
+                else "seeded" if meta.seeded else "full")
+        if got != want:
+            raise wf.WireError(
+                f"CT_CHUNK {chunk_idx} carries a {got} payload but the "
+                f"update's declared ct_kind expects {want}")
+        return chunk_idx, inner
+
+    def _parse_escrow(self, payload, meta: UpdateMeta | None,
+                      escrow_prev: dict) -> None:
+        """Record a TRANSCIPHER_SEED frame's escrow ciphertext, keeping the
+        prior value of its (cid, round) entry in `escrow_prev` for the
+        rollback."""
+        if meta is None:
+            raise wf.WireError("TRANSCIPHER_SEED before UPDATE_BEGIN")
+        if not meta.transcipher:
+            raise wf.WireError(
+                "TRANSCIPHER_SEED frame in a non-transcipher update "
+                "(declared ct_kind is not CT_TRANSCIPHER)")
+        sct, _ = wf.deserialize(payload, self.ctx, off=0)
+        if not isinstance(sct, _c.SeededCiphertext):
+            raise wf.WireError("TRANSCIPHER_SEED must nest a seeded-"
+                               f"ciphertext frame, got {type(sct).__name__}")
+        key = (meta.cid, meta.round)
+        if key not in escrow_prev:
+            escrow_prev[key] = self.escrow_seeds.get(key, _ESCROW_MISSING)
+        self.escrow_seeds[key] = sct
+
     # -- public API ---------------------------------------------------------
 
     def ingest(self, blob: bytes, weight: float) -> UpdateMeta:
@@ -465,96 +528,57 @@ class StreamIngest:
         prev_in_scale = self._in_scale
         acc_was_uninit = self._acc_ct is None
         try:
-            for ftype, _, payload in _frames(blob):
-                if ftype == wf.T_UPDATE_BEGIN:
+            off = 0
+            while off < len(blob):
+                # the frame cut and everything read from it on the host
+                with obs.span("wire.ingest.parse"):
                     with _parsing():
-                        cid, n_samples, rnd, n_chunks, kind = \
-                            _BEGIN.unpack_from(payload, 0)
-                    if kind not in _CT_KINDS:
-                        raise wf.WireError(
-                            f"unknown ct_kind {kind} in UPDATE_BEGIN; this "
-                            f"build implements {_CT_KINDS}")
-                    meta = UpdateMeta(cid, n_samples, rnd, n_chunks,
-                                      kind == CT_SEEDED,
-                                      kind == CT_TRANSCIPHER)
-                elif ftype == wf.T_CT_CHUNK:
-                    if meta is None:
-                        raise wf.WireError("CT_CHUNK before UPDATE_BEGIN")
-                    with _parsing():
-                        (chunk_idx,) = struct.unpack_from("<I", payload, 0)
-                    if chunk_idx >= meta.n_chunks:
-                        raise wf.WireError(
-                            f"chunk index {chunk_idx} >= declared "
-                            f"n_chunks {meta.n_chunks}")
-                    if chunk_idx in chunks_seen:
-                        raise wf.WireError(f"duplicate chunk {chunk_idx}")
-                    chunks_seen.add(chunk_idx)
-                    inner, _ = wf.deserialize(payload, self.ctx, off=4)
-                    # the nested payload kind must MATCH the declared
-                    # ct_kind: dispatching on isinstance alone would let a
-                    # masked chunk slip into a seeded/full update (or vice
-                    # versa), misclassifying UpdateMeta and the ledger —
-                    # a wire-consistency violation, rejected atomically
-                    got = ("masked" if isinstance(inner, _c.MaskedChunk)
-                           else "seeded"
-                           if isinstance(inner, _c.SeededCiphertext)
-                           else "full")
-                    want = ("masked" if meta.transcipher
-                            else "seeded" if meta.seeded else "full")
-                    if got != want:
-                        raise wf.WireError(
-                            f"CT_CHUNK {chunk_idx} carries a {got} payload "
-                            f"but the update's declared ct_kind expects "
-                            f"{want}")
+                        ftype, _, payload, off = wf.parse_frame(blob, off)
+                    if ftype == wf.T_UPDATE_BEGIN:
+                        meta = self._parse_begin(payload)
+                    elif ftype == wf.T_CT_CHUNK:
+                        chunk_idx, inner = self._parse_chunk(
+                            payload, meta, chunks_seen)
+                    elif ftype == wf.T_TRANSCIPHER_SEED:
+                        self._parse_escrow(payload, meta, escrow_prev)
+                    elif ftype == wf.T_UPDATE_END:
+                        saw_end = True
+                    elif ftype != wf.T_PLAIN_SEGMENT:
+                        raise wf.WireError(f"unexpected frame type "
+                                           f"{ftype:#x} in update stream")
+                if ftype == wf.T_CT_CHUNK:
                     if isinstance(inner, _c.MaskedChunk):
-                        inner = self._unmask_chunk(meta, inner)
-                    elif isinstance(inner, _c.SeededCiphertext):
-                        wf.check_seeded(inner, self.ctx)
-                        inner = inner.expand(self.ctx)
-                    self._buffer_chunk(chunk_idx, inner.data, inner.scale,
-                                       w_mont)
+                        with obs.span("wire.ingest.unmask"):
+                            inner = self._unmask_chunk(meta, inner)
+                            self._buffer_chunk(chunk_idx, inner.data,
+                                               inner.scale, w_mont)
+                    else:
+                        with obs.span("wire.ingest.expand"):
+                            if isinstance(inner, _c.SeededCiphertext):
+                                wf.check_seeded(inner, self.ctx)
+                                inner = inner.expand(self.ctx)
+                            self._buffer_chunk(chunk_idx, inner.data,
+                                               inner.scale, w_mont)
                     n_buffered += 1
-                elif ftype == wf.T_TRANSCIPHER_SEED:
-                    if meta is None:
-                        raise wf.WireError(
-                            "TRANSCIPHER_SEED before UPDATE_BEGIN")
-                    if not meta.transcipher:
-                        raise wf.WireError(
-                            "TRANSCIPHER_SEED frame in a non-transcipher "
-                            "update (declared ct_kind is not "
-                            "CT_TRANSCIPHER)")
-                    sct, _ = wf.deserialize(payload, self.ctx, off=0)
-                    if not isinstance(sct, _c.SeededCiphertext):
-                        raise wf.WireError(
-                            "TRANSCIPHER_SEED must nest a seeded-"
-                            f"ciphertext frame, got {type(sct).__name__}")
-                    escrow_key = (meta.cid, meta.round)
-                    if escrow_key not in escrow_prev:
-                        escrow_prev[escrow_key] = self.escrow_seeds.get(
-                            escrow_key, _ESCROW_MISSING)
-                    self.escrow_seeds[escrow_key] = sct
                 elif ftype == wf.T_PLAIN_SEGMENT:
                     # decode AND shape-validate inside the rollback scope —
                     # a wire-mutated dim must reject the whole update here;
                     # the fold after validation then cannot fail, so the
                     # success path needs no accumulator snapshot
-                    with _parsing():
-                        plain = _c.dequantize_plain(
-                            *wf._parse_plain_segment(payload))
-                    ref_shape = (self._acc_plain.shape
-                                 if self._acc_plain is not None
-                                 else plain_segments[0].shape
-                                 if plain_segments else None)
-                    if ref_shape is not None and plain.shape != ref_shape:
-                        raise wf.WireError(
-                            f"plain segment shape {plain.shape} does not "
-                            f"match this aggregation's {ref_shape}")
+                    with obs.span("wire.ingest.plain_decode"):
+                        with _parsing():
+                            plain = _c.dequantize_plain(
+                                *wf._parse_plain_segment(payload))
+                        ref_shape = (self._acc_plain.shape
+                                     if self._acc_plain is not None
+                                     else plain_segments[0].shape
+                                     if plain_segments else None)
+                        if ref_shape is not None \
+                                and plain.shape != ref_shape:
+                            raise wf.WireError(
+                                f"plain segment shape {plain.shape} does "
+                                f"not match this aggregation's {ref_shape}")
                     plain_segments.append(plain)
-                elif ftype == wf.T_UPDATE_END:
-                    saw_end = True
-                else:
-                    raise wf.WireError(f"unexpected frame type {ftype:#x} "
-                                       "in update stream")
             if meta is None or not saw_end:
                 raise wf.WireError("truncated update stream")
             if len(chunks_seen) != meta.n_chunks:
@@ -587,8 +611,9 @@ class StreamIngest:
             raise
         # validated above: these folds cannot fail, so no rollback is needed
         # past this point (and no per-ingest accumulator snapshot either)
-        for plain in plain_segments:
-            self._fold_plain_decoded(plain, weight)
+        with obs.span("wire.ingest.plain_fold"):
+            for plain in plain_segments:
+                self._fold_plain_decoded(plain, weight)
         self.flush()
         self._m_clients.inc()
         self._m_bytes.inc(len(blob))
@@ -673,15 +698,18 @@ class StreamIngest:
     def finalize(self) -> ProtectedUpdate:
         """-> aggregated ProtectedUpdate (ct scale = in_scale * delta).
         Raises WireError if nothing arrived or chunk indices have holes."""
-        self.flush()
-        if self.clients_ingested == 0 or self._acc_ct is None:
-            raise wf.WireError("no updates ingested")
-        n_chunks = max(self._acc_ct) + 1
-        if sorted(self._acc_ct) != list(range(n_chunks)):
-            raise wf.WireError("missing ciphertext chunks at finalize")
-        data = jnp.stack([jnp.moveaxis(self._acc_ct[b], -3, -2)
-                          for b in range(n_chunks)], axis=0)
-        ct = Ciphertext(data=data, scale=self._in_scale * self.ctx.delta)
-        plain = jnp.asarray(self._acc_plain if self._acc_plain is not None
-                            else np.zeros((0,), np.float32))
-        return ProtectedUpdate(ct=ct, plain=plain)
+        with obs.span("wire.finalize", n_updates=self.clients_ingested,
+                      launches=self.accum_launches):
+            self.flush()
+            if self.clients_ingested == 0 or self._acc_ct is None:
+                raise wf.WireError("no updates ingested")
+            n_chunks = max(self._acc_ct) + 1
+            if sorted(self._acc_ct) != list(range(n_chunks)):
+                raise wf.WireError("missing ciphertext chunks at finalize")
+            data = jnp.stack([jnp.moveaxis(self._acc_ct[b], -3, -2)
+                              for b in range(n_chunks)], axis=0)
+            ct = Ciphertext(data=data, scale=self._in_scale * self.ctx.delta)
+            plain = jnp.asarray(self._acc_plain
+                                if self._acc_plain is not None
+                                else np.zeros((0,), np.float32))
+            return ProtectedUpdate(ct=ct, plain=plain)

@@ -1,10 +1,13 @@
 """Telemetry layer (repro.obs): registry semantics, span nesting, the
 trace-JSONL round trip through tools/round_report.py, legacy-counter
-parity on the streaming ingest, and the REPRO_OBS=0 do-no-harm contract
+parity on the streaming ingest, the REPRO_OBS=0 do-no-harm contract
 (disabled obs leaves backend tokens, dispatch behaviour, and gold-KAT
-outputs untouched)."""
+outputs untouched), and the wire spans in a profiler trace with obs
+off."""
+import glob
 import json
 import os
+import struct
 import subprocess
 import sys
 
@@ -20,6 +23,7 @@ from repro.core.secure_agg import AggregatorConfig, SelectiveHEAggregator
 from repro.kernels import ops, ref
 from repro.obs.metrics import MetricsRegistry
 from repro.wire import compress as wc
+from repro.wire import format as wf
 from repro.wire import stream as ws
 
 import gold
@@ -153,14 +157,24 @@ def test_span_records_exception(obs_memory):
     assert tr.depth() == 0
 
 
-def test_disabled_span_is_shared_noop():
-    obs.configure(enabled=False, trace_path=None, reset=True)
-    sp = obs.span("anything", k=1)
-    assert sp is obs.NULL_SPAN
-    with sp as s:
-        s.set(ignored=True)      # must not raise
-    obs.event("nothing")         # no tracer instantiation needed
-    assert obs.trace_path() is None
+def test_disabled_span_is_shared_noop(tmp_path):
+    # disabled, a span is a profiler annotation only: it writes no JSONL
+    # event, opens no trace file, and tallies nothing with no profiler on
+    path = tmp_path / "never.jsonl"
+    obs.configure(enabled=False, trace_path=str(path), reset=True)
+    try:
+        sp = obs.span("anything", k=1)
+        assert sp.tracer is None
+        with sp as s:
+            s.set(ignored=True)      # must not raise
+        obs.event("nothing")
+        assert obs.trace_path() is None
+        assert not obs.get_tracer().events
+        obs.flush()
+        assert not path.exists()
+        assert obs.REGISTRY.get("obs_span_seconds", span="anything") is None
+    finally:
+        obs.configure(enabled=False, trace_path=None, reset=True)
 
 
 # ---------------------------------------------------------------------------
@@ -327,14 +341,14 @@ def test_gold_kats_bitexact_with_obs_disabled():
 def test_enabled_eager_dispatch_records(obs_memory):
     x = jnp.asarray(ref.rand_limbed_np(np.random.RandomState(0), CTX, (1,)))
     y_ref = np.asarray(ops.ntt_fwd(x, CTX))
-    c = obs.REGISTRY.get("kernel_op_launches_total", op="ntt_fwd",
-                         backend=ops.get_backend("ntt_fwd"))
-    assert c is not None and c.value >= 1
-    h = obs.REGISTRY.get("kernel_op_seconds", op="ntt_fwd",
-                         backend=ops.get_backend("ntt_fwd"))
-    assert h is not None and h.count >= 1
-    evs = [e for e in obs_memory.events if e.get("cat") == "kernel"]
-    assert any(e["args"].get("op") == "ntt_fwd" for e in evs)
+    evs = [e for e in obs_memory.events if e.get("cat") == "kernel"
+           and e["args"].get("op") == "ntt_fwd"]
+    assert evs
+    ev = evs[-1]
+    assert ev["name"] == "he.ntt_fwd" and ev["ph"] == "X"
+    assert ev["dur"] >= 0 and ev["args"]["eager"] is True
+    assert ev["args"]["backend"] == ops.get_backend("ntt_fwd")
+    assert ev["args"]["token"] == str(ops.backend_token())
     # and the instrumented result is the raw result
     obs.configure(enabled=False)
     np.testing.assert_array_equal(y_ref, np.asarray(ops.ntt_fwd(x, CTX)))
@@ -344,11 +358,12 @@ def test_kernel_launch_context_manager(obs_memory):
     with obs.kernel_launch("fake_op", ops.backend_token(), rows=3) as kl:
         out = kl.done(jnp.ones((2, 2)))
     assert float(out.sum()) == 4.0
-    ev = [e for e in obs_memory.events if e.get("cat") == "kernel"][-1]
+    evs = [e for e in obs_memory.events if e.get("cat") == "kernel"]
+    assert len(evs) == 1
+    ev = evs[0]
+    assert ev["name"] == "he.fake_op" and ev["ph"] == "X" and ev["dur"] >= 0
     assert ev["args"]["op"] == "fake_op" and ev["args"]["rows"] == 3
-    assert "token" in ev["args"]
-    h = obs.REGISTRY.get("kernel_launch_seconds", op="fake_op", backend="")
-    assert h is not None and h.count >= 1
+    assert ev["args"]["token"] == str(ops.backend_token())
 
 
 # ---------------------------------------------------------------------------
@@ -370,3 +385,141 @@ def test_orchestrator_round_span_coverage(obs_memory):
     names = {e["name"] for e in obs_memory.events}
     assert {"round", "client", "local_train", "aggregate",
             "recover"} <= names
+
+
+# ---------------------------------------------------------------------------
+# spans in the profiler trace, with REPRO_OBS off
+# ---------------------------------------------------------------------------
+
+
+def _profiled(fn, tmp_path):
+    """fn() under a profiler session -> (its result, {repro.* name:
+    [(start_ns, end_ns), ...]} of the host events in the trace)."""
+    from jax.profiler import ProfileData
+
+    with jax.profiler.trace(str(tmp_path)):
+        out = fn()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    spans: dict = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("repro."):
+                    spans.setdefault(ev.name, []).append((ev.start_ns,
+                                                          ev.end_ns))
+    return out, spans
+
+
+def _inside(inner, outer) -> bool:
+    return all(any(os_ <= s and e <= oe for os_, oe in outer)
+               for s, e in inner)
+
+
+def _tally(name: str):
+    h = obs.REGISTRY.get("obs_span_seconds", span=name)
+    return (0, 0.0) if h is None else (h.count, h.sum)
+
+
+def _seeded_update(agg, m, cid=0):
+    c = jax.tree_util.tree_map(lambda x: x + 0.05 * (cid + 1), m)
+    upd = agg.client_protect_seeded(c, SK, jax.random.PRNGKey(40 + cid),
+                                    a_seed=900 + cid)
+    return upd, wc.seed_compress(upd.ct, 900 + cid)
+
+
+def test_ingest_spans_reach_profiler_with_obs_off(tmp_path):
+    obs.configure(enabled=False, trace_path=None, reset=True)
+    agg, m = make_agg()
+    upd, sct = _seeded_update(agg, m)
+    blob = ws.pack_update_frames(upd, cid=0, n_samples=3, rnd=0,
+                                 seeded=sct)
+    n = agg.part.n_chunks
+    names = ("wire.ingest.parse", "wire.ingest.expand",
+             "wire.ingest.plain_decode", "wire.ingest.plain_fold",
+             "wire.flush")
+    before = {k: _tally(k) for k in names}
+    ing = ws.StreamIngest(CTX)
+    _, spans = _profiled(lambda: ing.ingest(blob, 1.0), tmp_path)
+    (outer,) = spans["repro.wire.ingest"]
+    for name in names:
+        assert _inside(spans["repro." + name], [outer]), name
+    # one parse per frame (UPDATE_BEGIN, each CT_CHUNK, PLAIN_SEGMENT,
+    # UPDATE_END), one expand per chunk, one of each of the rest
+    counts = {k: len(spans["repro." + k]) for k in names}
+    assert counts == {"wire.ingest.parse": n + 3, "wire.ingest.expand": n,
+                      "wire.ingest.plain_decode": 1,
+                      "wire.ingest.plain_fold": 1, "wire.flush": 1}
+    assert "repro.wire.ingest.unmask" not in spans
+    # the in-process tally counts the same spans while the profiler records
+    for k in names:
+        c, sec = _tally(k)
+        assert c - before[k][0] == counts[k]
+        assert 0.0 < sec - before[k][1] < (outer[1] - outer[0]) * 1e-9
+    # and nothing of the JSONL layer ran
+    assert not obs.get_tracer().events
+    assert obs.trace_path() is None
+    assert ing.clients_ingested == 1 and ing.accum_launches == 1
+
+
+def _frames_reference(upd, *, cid, n_samples, rnd, seeded, plain_codec):
+    """The update's frames laid out from the wire primitives alone: what
+    pack_update_frames must emit, byte for byte."""
+    n = int(upd.ct.data.shape[0])
+    kind = ws.CT_SEEDED if seeded is not None else ws.CT_FULL
+    out = [wf.frame(wf.T_UPDATE_BEGIN, struct.pack("<IIIIB", cid, n_samples,
+                                                   rnd, n, kind))]
+    host = np.asarray(seeded.c0 if seeded is not None else upd.ct.data)
+    for b in range(n):
+        if seeded is not None:
+            inner = wf.serialize_seeded_ciphertext(wc.SeededCiphertext(
+                c0=host[b:b + 1], seed=seeded.seed, scale=seeded.scale,
+                chunk_offset=b, derive=seeded.derive))
+        else:
+            inner = wf.serialize_ciphertext(cipher.Ciphertext(
+                data=host[b:b + 1], scale=upd.ct.scale))
+        out.append(wf.frame(wf.T_CT_CHUNK, struct.pack("<I", b) + inner))
+    arr, q = wc.quantize_plain(np.asarray(upd.plain), plain_codec)
+    out.append(wf.serialize_plain_segment(arr, plain_codec, q))
+    out.append(wf.frame(wf.T_UPDATE_END, b""))
+    return b"".join(out)
+
+
+@pytest.mark.parametrize("seeded,codec", [(True, "f32"), (False, "f16"),
+                                          (True, "i8")])
+def test_pack_spans_and_bytes_unchanged(tmp_path, seeded, codec):
+    obs.configure(enabled=False, trace_path=None, reset=True)
+    agg, m = make_agg()
+    upd, sct = _seeded_update(agg, m, cid=1)
+    sct = sct if seeded else None
+    kw = dict(cid=1, n_samples=5, rnd=2, seeded=sct, plain_codec=codec)
+    blob, spans = _profiled(lambda: ws.pack_update_frames(upd, **kw),
+                            tmp_path)
+    assert blob == _frames_reference(upd, **kw)
+    outer = spans["repro.wire.pack"]
+    assert len(outer) == 1
+    for k in ("fetch", "ct_frames", "plain", "join"):
+        got = spans["repro.wire.pack." + k]
+        assert len(got) == 1 and _inside(got, outer), k
+    assert not obs.get_tracer().events
+
+
+def test_span_tallies_only_while_profiling(tmp_path):
+    obs.configure(enabled=False, trace_path=None, reset=True)
+    name = "test.tally"
+    with obs.span(name):
+        pass
+    assert _tally(name) == (0, 0.0)
+
+    def two():
+        for _ in range(2):
+            with obs.span(name, cid=7):
+                pass
+
+    _, spans = _profiled(two, tmp_path)
+    assert len(spans["repro." + name]) == 2
+    c, sec = _tally(name)
+    assert c == 2 and sec > 0.0
+    with obs.span(name):
+        pass
+    assert _tally(name)[0] == 2
