@@ -1,9 +1,12 @@
 """Flatten/partition/pack model parameters for selective HE.
 
 The FL/HE boundary works on a single flat f32 vector per model (the paper's
-``flatten``/``reshape`` APIs, Table 3).  Selection masks are *static* per FL
-task (the paper fixes M after round 1), so the mask partition is realized as
-constant index arrays -> jit-friendly gathers/scatters with static shapes.
+``flatten``/``reshape`` APIs, Table 3).  Selection masks are *static* and
+public per FL task (the paper fixes M after round 1), so the mask partition
+is a pair of host index arrays.  Splitting and merging only place values,
+and both run on the host: a scalar gather of the whole model on the device
+runs far below the HBM roofline (11 s at qwen1.5-0.5b width on a TPU v5e)
+and holds its index array and output in HBM besides.
 """
 from __future__ import annotations
 
@@ -12,6 +15,8 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro import obs
 
 
 @dataclasses.dataclass(frozen=True)
@@ -66,8 +71,9 @@ def unflatten_params(vec, spec: FlatSpec):
 class MaskPartition:
     """Static index arrays splitting a flat vector by a boolean mask.
 
-    ``enc_idx``/``plain_idx`` are host numpy int32 arrays (constants baked
-    into the jitted round step).  ``n_enc_padded`` pads the encrypted segment
+    ``enc_idx``/``plain_idx`` are sorted host numpy int32 arrays, and the
+    fields are what the wire serializes (wire/format.py
+    ``serialize_partition``).  ``n_enc_padded`` pads the encrypted segment
     to a whole number of CKKS slot blocks.
     """
 
@@ -108,12 +114,20 @@ def make_partition(mask: np.ndarray, slots: int) -> MaskPartition:
 
 
 def split_by_mask(vec, part: MaskPartition):
-    """f32[P] -> (enc f32[n_chunks, slots] zero-padded, plain f32[n_plain])."""
-    enc = vec[jnp.asarray(part.enc_idx)]
-    pad = part.n_enc_padded - part.n_enc
-    enc = jnp.pad(enc, (0, pad)).reshape(part.n_chunks, part.slots)
-    plain = vec[jnp.asarray(part.plain_idx)]
-    return enc, plain
+    """f32[P] -> (enc f32[n_chunks, slots] zero-padded, plain f32[n_plain]).
+
+    `vec` is copied to the host once and split there, in the
+    `protect.split` span: the encrypted values by a host gather of
+    ``enc_idx`` (they go to the device as the encrypt's argument), the
+    plaintext partition by one order-preserving compaction, which stays on
+    the host for the wire pack.  Both come back as host numpy arrays of
+    `vec`'s dtype."""
+    with obs.span("protect.split"):
+        host = np.asarray(vec)
+        enc = np.zeros(part.n_enc_padded, dtype=host.dtype)
+        np.take(host, part.enc_idx, out=enc[:part.n_enc])
+        return (enc.reshape(part.n_chunks, part.slots),
+                np.delete(host, part.enc_idx))
 
 
 def merge_by_mask(enc_chunks, plain, part: MaskPartition):

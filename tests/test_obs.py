@@ -504,6 +504,22 @@ def test_pack_spans_and_bytes_unchanged(tmp_path, seeded, codec):
     assert not obs.get_tracer().events
 
 
+def test_protect_split_span_once_per_protect(tmp_path):
+    obs.configure(enabled=False, trace_path=None, reset=True)
+    agg, m = make_agg()
+    before = _tally("protect.split")
+
+    def protect_twice():
+        return [_seeded_update(agg, m, cid=c)[0] for c in range(2)]
+
+    upds, spans = _profiled(protect_twice, tmp_path)
+    assert len(spans["repro.protect.split"]) == 2
+    c, sec = _tally("protect.split")
+    assert c - before[0] == 2 and sec > before[1]
+    # the split's plaintext partition reaches the pack on the host
+    assert all(type(u.plain) is np.ndarray for u in upds)
+
+
 def test_span_tallies_only_while_profiling(tmp_path):
     obs.configure(enabled=False, trace_path=None, reset=True)
     name = "test.tally"

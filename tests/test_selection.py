@@ -9,8 +9,10 @@ Invariants pinned here:
   * ties on |sensitivity| break deterministically by index (lowest wins)
   * make_partition / split_by_mask / merge_by_mask round-trip any mask —
     empty, full, non-slot-aligned, ragged last chunk
+  * the host split gives the bits of the device gathers it replaced
 """
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -166,3 +168,111 @@ def test_partition_roundtrip_adversarial(mask):
        st.integers(1, 16))
 def test_partition_roundtrip_fuzz(bits, slots):
     _roundtrip(np.asarray(bits, dtype=bool), slots=slots)
+
+
+# ---------------------------------------------------------------------------
+# host split against the device gathers, bit for bit
+# ---------------------------------------------------------------------------
+
+SPLIT_N, SPLIT_SLOTS = 4096, 16
+
+
+def _gather_split(vec, part):
+    """The split as the device did it: jnp gathers of both index arrays."""
+    enc = vec[jnp.asarray(part.enc_idx)]
+    enc = jnp.pad(enc, (0, part.n_enc_padded - part.n_enc))
+    return (enc.reshape(part.n_chunks, part.slots),
+            vec[jnp.asarray(part.plain_idx)])
+
+
+def _split_mask(kind):
+    rng = np.random.RandomState(7)
+    if kind == "empty":
+        return np.zeros(SPLIT_N, dtype=bool)
+    if kind == "full":
+        return np.ones(SPLIT_N, dtype=bool)
+    if kind == "ragged":                 # 41 = 2 whole chunks + 9
+        mask = np.zeros(SPLIT_N, dtype=bool)
+        mask[rng.choice(SPLIT_N, 41, replace=False)] = True
+        return mask
+    if kind == "slot_blocks":            # exactly 3 whole chunks
+        mask = np.zeros(SPLIT_N, dtype=bool)
+        mask[rng.choice(SPLIT_N, 3 * SPLIT_SLOTS, replace=False)] = True
+        return mask
+    if kind == "edges":                  # the vector's first and last value
+        mask = np.zeros(SPLIT_N, dtype=bool)
+        mask[[0, SPLIT_N - 1]] = True
+        return mask
+    if kind == "recipe_leaves":          # top 1% plus whole first/last leaf
+        offsets, sizes = _layout(SPLIT_N, n_leaves=5)
+        return selection.build_mask(rng.rand(SPLIT_N), "recipe", 0.01,
+                                    offsets=offsets, sizes=sizes)
+    return selection.random_mask(0.01, SPLIT_N, seed=7)   # random_1pct
+
+
+def _bits(x):
+    x = np.asarray(x)
+    return x.dtype, x.shape, x.view(np.uint32)
+
+
+@pytest.mark.parametrize("kind", ["empty", "full", "random_1pct", "ragged",
+                                  "slot_blocks", "edges", "recipe_leaves"])
+def test_host_split_matches_device_gather_bits(kind):
+    part = packing.make_partition(_split_mask(kind), SPLIT_SLOTS)
+    host = np.random.RandomState(3).randn(SPLIT_N).astype(np.float32)
+    host[:4] = [-0.0, np.inf, -np.inf, 1e-40]       # bits a copy keeps
+    vec = jnp.asarray(host)
+    got = packing.split_by_mask(vec, part)
+    assert all(type(x) is np.ndarray for x in got)   # stays on the host
+    want = _gather_split(vec, part)
+    for g, w in zip(got, want):
+        gd, gs, gb = _bits(g)
+        wd, ws, wb = _bits(w)
+        assert gd == wd == np.float32 and gs == ws
+        np.testing.assert_array_equal(gb, wb)
+    assert got[0].shape == (part.n_chunks, SPLIT_SLOTS)
+    if kind == "ragged":
+        assert part.n_enc % SPLIT_SLOTS
+    if kind == "slot_blocks":
+        assert part.n_enc == part.n_enc_padded
+    if kind == "edges":
+        assert got[0][0, :2].tolist() == [host[0], host[-1]]
+    if kind == "recipe_leaves":
+        offsets, sizes = _layout(SPLIT_N, n_leaves=5)
+        assert part.enc_idx[0] == 0 and part.n_enc > sizes[0] + sizes[-1]
+
+
+@pytest.mark.parametrize("codec,dp_b", [("f32", 0.0), ("i8", 0.0),
+                                        ("f32", 0.05)])
+def test_seeded_protect_and_pack_host_split_bytes(monkeypatch, codec, dp_b):
+    """The host split packs the blob the device gathers packed, byte for
+    byte, DP noise under a fixed key included, and leaves the partition's
+    wire bytes as they were."""
+    from repro.core.ckks import cipher, params as ckks_params
+    from repro.core.secure_agg import AggregatorConfig, SelectiveHEAggregator
+    from repro.fl.client import FLClient
+    from repro.wire import compress as wc, format as wf
+
+    class _NoModel:
+        loss_fn = staticmethod(lambda params, batch: 0.0)
+
+    ctx = ckks_params.make_test_context(n_poly=256, n_limbs=2, delta_bits=20)
+    sk, _ = cipher.keygen(ctx, jax.random.PRNGKey(0))
+    r = np.random.RandomState(1)
+    m = {"w1": jnp.asarray(r.randn(40, 10), jnp.float32),
+         "b1": jnp.asarray(r.randn(50), jnp.float32)}
+    agg = SelectiveHEAggregator.build(
+        ctx, m, np.abs(r.randn(450)), AggregatorConfig(p_ratio=0.4, dp_b=dp_b))
+    part_bytes = wf.serialize_partition(agg.part)
+    cli = FLClient(2, _NoModel(), None)
+    policy = wc.WirePolicy(seed_ciphertexts=True, plain_codec=codec)
+
+    def pack():
+        return cli.protect_and_pack(agg, m, rnd=1, policy=policy, sk=sk,
+                                    key=jax.random.PRNGKey(77),
+                                    mode="seeded")
+
+    host = pack()
+    assert wf.serialize_partition(agg.part) == part_bytes
+    monkeypatch.setattr(packing, "split_by_mask", _gather_split)
+    assert host == pack()
